@@ -195,16 +195,15 @@ def fabric_rows(shapes: List, iters: int = 10) -> List[Dict]:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     from repro.core.mesh_engine import (NODE_AXIS, make_node_mesh,
                                         mesh_exchange)
     n_dev = len(jax.devices())
     mesh = make_node_mesh(n_dev)
-    fn = jax.jit(shard_map(mesh_exchange, mesh=mesh,
-                           in_specs=PS(NODE_AXIS), out_specs=PS(NODE_AXIS),
-                           check_rep=False))
+    fn = jax.jit(jax.shard_map(mesh_exchange, mesh=mesh,
+                               in_specs=PS(NODE_AXIS),
+                               out_specs=PS(NODE_AXIS), check_vma=False))
     rows = []
     for slots, words in shapes:
         x = jnp.ones((n_dev, n_dev, slots, words), jnp.int32)
@@ -224,37 +223,19 @@ def fabric_bench(n_devices: int = 8, iters: int = 10) -> Dict:
     """``all_to_all`` fabric timings on ``n_devices`` real host devices.
 
     The device count must be forced before jax initializes, so the
-    measurement runs in a subprocess (mirroring the mesh parity tests);
-    if that fails (constrained sandbox), it degrades to an in-process run
-    over however many devices already exist — the schema is identical.
+    measurement runs in a child process (``benchmarks.child``); a failed
+    child ends the run non-zero.
     """
-    import subprocess
+    import pathlib
     import sys
-    import textwrap
-    script = textwrap.dedent(f"""
-        import os, json
-        os.environ['XLA_FLAGS'] = \
-            '--xla_force_host_platform_device_count={n_devices}'
-        import sys; sys.path.insert(0, 'src'); sys.path.insert(0, '.')
-        from benchmarks.exchange_bench import fabric_rows, _FABRIC_SHAPES
-        print('FABRIC_JSON ' + json.dumps(
-            fabric_rows(list(_FABRIC_SHAPES), iters={iters})))
-    """)
-    rows = None
-    try:
-        r = subprocess.run([sys.executable, "-c", script],
-                           capture_output=True, text=True, timeout=600)
-        for line in r.stdout.splitlines():
-            if line.startswith("FABRIC_JSON "):
-                rows = json.loads(line[len("FABRIC_JSON "):])
-    except (OSError, subprocess.SubprocessError, ValueError):
-        rows = None
-    in_process = rows is None
-    if in_process:
-        rows = fabric_rows(list(_FABRIC_SHAPES), iters=iters)
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from benchmarks.child import run_child
+    rows = run_child("benchmarks.exchange_bench", "fabric_rows",
+                     ([list(s) for s in _FABRIC_SHAPES], iters), n_devices,
+                     timeout=600)
     return {"collective": "mesh_all_to_all",
             "n_devices": rows[0]["n_devices"] if rows else 0,
-            "in_process_fallback": in_process, "rows": rows}
+            "rows": rows}
 
 
 def kernel_bench(iters: int = 5) -> List[Dict]:
@@ -428,4 +409,6 @@ def main(argv=None) -> Dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -34,9 +34,8 @@ from __future__ import annotations
 
 import argparse
 import json
-import subprocess
+import pathlib
 import sys
-import textwrap
 import time
 from typing import Dict, List
 
@@ -45,7 +44,7 @@ def bench_cells(n: int, q: int, w: int, iters: int) -> List[Dict]:
     """Run the uniform-vs-ragged cells for one node count (in-process).
 
     Must run under a process that already sees ``n`` devices — use
-    ``run_subprocess`` from the harness entry point.
+    ``benchmarks.child.run_child`` from the harness entry point.
     """
     import jax.numpy as jnp
     import numpy as np
@@ -131,30 +130,6 @@ def bench_cells(n: int, q: int, w: int, iters: int) -> List[Dict]:
     return rows
 
 
-def run_subprocess(n: int, q: int, w: int, iters: int,
-                   timeout: int = 900) -> List[Dict]:
-    """One node count in a device-forced subprocess (in-process fallback)."""
-    script = textwrap.dedent(f"""
-        import os, json
-        os.environ['XLA_FLAGS'] = \
-            '--xla_force_host_platform_device_count={n}'
-        import sys; sys.path.insert(0, 'src'); sys.path.insert(0, '.')
-        from benchmarks.mesh_bench import bench_cells
-        print('MESH_BENCH_JSON ' + json.dumps(
-            bench_cells({n}, {q}, {w}, {iters})))
-    """)
-    try:
-        r = subprocess.run([sys.executable, "-c", script],
-                           capture_output=True, text=True, timeout=timeout)
-        for line in r.stdout.splitlines():
-            if line.startswith("MESH_BENCH_JSON "):
-                return json.loads(line[len("MESH_BENCH_JSON "):])
-        sys.stderr.write(r.stdout + r.stderr)
-    except (OSError, subprocess.SubprocessError, ValueError) as e:
-        sys.stderr.write(f"mesh bench subprocess N={n} failed: {e}\n")
-    return []
-
-
 def summarize(rows: List[Dict]) -> Dict:
     """Per (N, workload): ragged-vs-uniform byte and wall-time ratios."""
     by = {}
@@ -191,11 +166,14 @@ def main(argv=None) -> Dict:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--out", default="BENCH_pr5.json")
     args = ap.parse_args(argv)
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from benchmarks.child import run_child
     nodes = ([8, 32] if args.quick
              else [int(x) for x in args.nodes.split(",")])
     rows: List[Dict] = []
     for n in nodes:
-        got = run_subprocess(n, args.batch, args.words, args.iters)
+        got = run_child("benchmarks.mesh_bench", "bench_cells",
+                        (n, args.batch, args.words, args.iters), n)
         for r in got:
             print(f"{r['backend']:8s} {r['workload']:7s} N={r['n_nodes']:3d} "
                   f"exec={r['executor']:8s} "
@@ -204,8 +182,6 @@ def main(argv=None) -> Dict:
         rows += got
     # re-measure the fabric so the committed artifact makes
     # exchange_select.fabric_model (executor pick, migration gate) measured
-    import pathlib
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
     from benchmarks.exchange_bench import fabric_bench
     from repro.core import obs
     result = {
@@ -232,4 +208,6 @@ def main(argv=None) -> Dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

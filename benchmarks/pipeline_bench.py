@@ -44,9 +44,8 @@ from __future__ import annotations
 
 import argparse
 import json
-import subprocess
+import pathlib
 import sys
-import textwrap
 import time
 from typing import Dict, List
 
@@ -73,7 +72,6 @@ def bench_node(n: int, q: int, w: int, iters: int) -> Dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     from benchmarks.exchange_bench import _FABRIC_SHAPES, fabric_rows
@@ -110,10 +108,10 @@ def bench_node(n: int, q: int, w: int, iters: int) -> Dict:
                 config=cfg, global_sum=me.mesh_global_sum, shift=shift,
                 update_meta=False)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             _w, mesh=mesh,
             in_specs=(state_specs, req, req, req, req, req),
-            out_specs=state_specs, check_rep=False))
+            out_specs=state_specs, check_vma=False))
 
     def overlap_cell(path, cfg_of, ph, rounds_of):
         mode = jnp.full((n, q), int(LayoutMode.DIST_HASH), jnp.int32)
@@ -190,8 +188,8 @@ def bench_node(n: int, q: int, w: int, iters: int) -> Dict:
                 global_sum=me.mesh_global_sum, state=st, client=cl)
             return out_st
 
-        return jax.jit(shard_map(_x, mesh=mesh, in_specs=(req,) * 5,
-                                 out_specs=req, check_rep=False))
+        return jax.jit(jax.shard_map(_x, mesh=mesh, in_specs=(req,) * 5,
+                                     out_specs=req, check_vma=False))
 
     carry_times = {}
     for pipe in (False, True):
@@ -239,30 +237,6 @@ def bench_node(n: int, q: int, w: int, iters: int) -> Dict:
             "cells": cells, "write_heavy": write_heavy}
 
 
-def run_subprocess(n: int, q: int, w: int, iters: int,
-                   timeout: int = 900) -> Dict:
-    """One node count in a device-forced subprocess."""
-    script = textwrap.dedent(f"""
-        import os, json
-        os.environ['XLA_FLAGS'] = \
-            '--xla_force_host_platform_device_count={n}'
-        import sys; sys.path.insert(0, 'src'); sys.path.insert(0, '.')
-        from benchmarks.pipeline_bench import bench_node
-        print('PIPE_BENCH_JSON ' + json.dumps(
-            bench_node({n}, {q}, {w}, {iters})))
-    """)
-    try:
-        r = subprocess.run([sys.executable, "-c", script],
-                           capture_output=True, text=True, timeout=timeout)
-        for line in r.stdout.splitlines():
-            if line.startswith("PIPE_BENCH_JSON "):
-                return json.loads(line[len("PIPE_BENCH_JSON "):])
-        sys.stderr.write(r.stdout + r.stderr)
-    except (OSError, subprocess.SubprocessError, ValueError) as e:
-        sys.stderr.write(f"pipeline bench subprocess N={n} failed: {e}\n")
-    return {}
-
-
 def main(argv=None) -> Dict:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
@@ -273,15 +247,16 @@ def main(argv=None) -> Dict:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--out", default="BENCH_pr10.json")
     args = ap.parse_args(argv)
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from benchmarks.child import run_child
     nodes = ([8, 32] if args.quick
              else [int(x) for x in args.nodes.split(",")])
     cells: List[Dict] = []
     write_heavy: List[Dict] = []
     fabric = None
     for n in nodes:
-        got = run_subprocess(n, args.batch, args.words, args.iters)
-        if not got:
-            continue
+        got = run_child("benchmarks.pipeline_bench", "bench_node",
+                        (n, args.batch, args.words, args.iters), n)
         for c in got["cells"]:
             print(f"{c['path']:9s} N={c['n_nodes']:3d} "
                   f"sync={c['sync_us']:9.1f}us "
@@ -297,8 +272,6 @@ def main(argv=None) -> Dict:
         # regression bounds key on)
         fabric = {"collective": "mesh_all_to_all", "n_devices": n,
                   "fit": got["fabric_fit"], "rows": got["fabric_rows"]}
-    import pathlib
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
     from repro.core import exchange_select, obs
     result = {
         "meta": {
@@ -322,4 +295,6 @@ def main(argv=None) -> Dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

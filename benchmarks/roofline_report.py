@@ -75,4 +75,6 @@ def dryrun_summary(out_dir: str = "results/dryrun") -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print(markdown_table())

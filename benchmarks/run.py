@@ -13,7 +13,7 @@ Prints ``name,us_per_call,derived`` CSV.  Sections:
   table3 ablations
   table4 decision-pipeline cost (measured)
   engine REAL wall-clock of the BB data plane
-  kernel interpret-mode kernel latencies
+  kernel kernel latencies (interpret mode off-TPU)
   roofline per-(arch×shape) dry-run roofline terms (if results exist)
 """
 from __future__ import annotations
@@ -58,6 +58,7 @@ def main() -> None:
         sections["table2"] = tables.table2_accuracy
 
     print("name,us_per_call,derived")
+    failed = []
     with obs.activate(rec):
         for name, fn in sections.items():
             if want and name not in want:
@@ -66,15 +67,20 @@ def main() -> None:
                 with obs.span(f"bench.{name}", cat="bench"):
                     for row in fn():
                         print(f"{row[0]},{row[1]:.1f},{row[2]}")
-            except Exception as e:  # keep the harness robust
+            except Exception as e:  # report it, run the rest, then fail
                 print(f"{name}.ERROR,0.0,{type(e).__name__}:{e}",
                       file=sys.stdout)
+                failed.append(name)
     if rec is not None:
         obs.write_recording(rec, args.trace_out,
                             meta=obs.provenance_meta())
         print(f"# wrote {args.trace_out} ({len(rec.spans)} spans)",
               file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

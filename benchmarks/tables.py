@@ -138,10 +138,13 @@ def engine_microbench() -> List[Row]:
 
 
 def kernel_microbench() -> List[Row]:
-    """Interpret-mode kernel wall times (correctness-path latency)."""
+    """Kernel wall times; each row says whether the Pallas interpreter
+    (off-TPU) or the compiled kernel ran."""
     import jax
+    from repro.kernels import default_interpret
     from repro.kernels.chunk_router.ops import route_chunks
     from repro.kernels.fletcher.ops import fletcher_checksum
+    mode = f"interpret_mode={default_interpret()}"
     rows = []
     rng = np.random.RandomState(0)
     ph = jnp.asarray(rng.randint(1, 1 << 30, 4096), jnp.int32)
@@ -154,7 +157,7 @@ def kernel_microbench() -> List[Row]:
         d, c = route_chunks(ph, cid, cl, mode=3, n_nodes=64)
     jax.block_until_ready(d)
     rows.append(("kernel.chunk_router.4096", (time.time() - t0) / 5 * 1e6,
-                 "interpret_mode=True"))
+                 mode))
     x = jnp.asarray(rng.randint(0, 1 << 30, 1 << 16), jnp.int32)
     cs = fletcher_checksum(x)
     jax.block_until_ready(cs)
@@ -163,5 +166,5 @@ def kernel_microbench() -> List[Row]:
         cs = fletcher_checksum(x)
     jax.block_until_ready(cs)
     rows.append(("kernel.fletcher.64Kwords", (time.time() - t0) / 5 * 1e6,
-                 "interpret_mode=True"))
+                 mode))
     return rows

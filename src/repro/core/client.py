@@ -292,8 +292,6 @@ class BBClient:
             kind=exchange if exchange != "auto" else "compacted",
             budget=budget, meta_budget=meta_budget, capacity=capacity,
             lossless=lossless, pipeline=self.pipeline)
-        self.state = (state if state is not None
-                      else bb.init_state(self.n_nodes, cap, words, mcap))
         self._path_codes = functools.lru_cache(maxsize=1 << 16)(
             self._path_codes_uncached)
         self._pick_cache: Dict[int, str] = {}
@@ -306,6 +304,12 @@ class BBClient:
         if not self._is_mesh and backend != "stacked":
             raise ValueError(f"unknown backend {backend!r}; pass "
                              "'stacked' or a jax.sharding.Mesh")
+        if state is None and self._is_mesh:
+            # created sharded: no chip ever holds the whole table
+            from repro.core.mesh_engine import init_mesh_state
+            state = init_mesh_state(backend, self.n_nodes, cap, words, mcap)
+        self.state = (state if state is not None
+                      else bb.init_state(self.n_nodes, cap, words, mcap))
         self._mesh_ops: Dict[bb.ExchangeConfig, Tuple] = {}
         self._mesh_migrate: Dict[bb.ExchangeConfig, object] = {}
         self._mesh_probe: Dict[bb.ExchangeConfig, object] = {}

@@ -352,8 +352,10 @@ def plan_mesh_ragged_spec(dest: jax.Array, valid: jax.Array, n_nodes: int,
     matches both the stacked layout and the client's global view).
     """
     from repro.core import exchange_select
-    d = jnp.where(jnp.asarray(valid), jnp.asarray(dest).astype(jnp.int32),
-                  n_nodes)
+    # host copies first: dest may be the node-sharded output of a mesh op,
+    # and a Pallas kernel cannot take a sharded array outside shard_map
+    d = jnp.asarray(np.where(np.asarray(valid),
+                             np.asarray(dest).astype(np.int32), n_nodes))
     q = d.shape[1]
     hist = np.asarray(histogram_rows2d(d, n_bins=n_nodes + 1)[:, :n_nodes])
     if hist.shape[0] == 0:

@@ -21,12 +21,12 @@ heterogeneous ``LayoutPolicy`` reaches the routing triplet under shard_map.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from repro.core import burst_buffer as bb
 from repro.core import obs
@@ -34,6 +34,18 @@ from repro.core.exchange_plan import MeshRaggedSpec, RaggedSpec
 from repro.core.policy import LayoutPolicy, as_policy
 
 NODE_AXIS = "node"
+
+
+def init_mesh_state(mesh: Mesh, n_nodes: int, cap: int, words: int,
+                    mcap: int) -> bb.BBState:
+    """Fresh node tables created in place, sharded over the node axis.
+
+    ``bb.init_state`` followed by a reshard would first build every table
+    on one device; jitting it with node-sharded outputs makes each device
+    allocate only its own rows."""
+    return jax.jit(functools.partial(bb.init_state, n_nodes, cap, words,
+                                     mcap),
+                   out_shardings=NamedSharding(mesh, PS(NODE_AXIS)))()
 
 
 def mesh_exchange(x: jax.Array) -> jax.Array:
@@ -166,26 +178,26 @@ def build_mesh_ops(mesh: Mesh, policy,
         lambda _: PS(NODE_AXIS), bb.init_state(1, 1, 1, 1))
 
     dargs = (0,) if donate else ()
-    write = jax.jit(shard_map(
+    write = jax.jit(jax.shard_map(
         _write, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec),
-        out_specs=state_specs, check_rep=False), donate_argnums=dargs)
-    read = jax.jit(shard_map(
+        out_specs=state_specs, check_vma=False), donate_argnums=dargs)
+    read = jax.jit(jax.shard_map(
         _read, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec),
-        out_specs=(req_spec, req_spec), check_rep=False))
-    meta = jax.jit(shard_map(
+        out_specs=(req_spec, req_spec), check_vma=False))
+    meta = jax.jit(jax.shard_map(
         _meta, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec, req_spec),
         out_specs=(state_specs, req_spec, req_spec, req_spec),
-        check_rep=False), donate_argnums=dargs)
-    read_loc = jax.jit(shard_map(
+        check_vma=False), donate_argnums=dargs)
+    read_loc = jax.jit(jax.shard_map(
         _read_loc, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec),
-        out_specs=(req_spec, req_spec), check_rep=False))
+        out_specs=(req_spec, req_spec), check_vma=False))
     return write, read, meta, read_loc
 
 
@@ -219,11 +231,11 @@ def build_mesh_migrate(mesh: Mesh, policy,
 
     state_specs = jax.tree_util.tree_map(
         lambda _: PS(NODE_AXIS), bb.init_state(1, 1, 1, 1))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _migrate, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec),
-        out_specs=(state_specs, req_spec, req_spec), check_rep=False),
+        out_specs=(state_specs, req_spec, req_spec), check_vma=False),
         donate_argnums=(0,) if donate else ())
 
 
@@ -257,10 +269,10 @@ def build_mesh_probe(mesh: Mesh, policy,
 
     state_specs = jax.tree_util.tree_map(
         lambda _: PS(NODE_AXIS), bb.init_state(1, 1, 1, 1))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _probe, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec),
-        out_specs=(req_spec, req_spec), check_rep=False))
+        out_specs=(req_spec, req_spec), check_vma=False))
 
 
 def build_telemetry_reduce(mesh: Mesh):
@@ -277,9 +289,9 @@ def build_telemetry_reduce(mesh: Mesh):
     def _reduce(counts):
         return jax.lax.psum(jnp.sum(counts, axis=0), NODE_AXIS)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _reduce, mesh=mesh, in_specs=PS(NODE_AXIS), out_specs=PS(),
-        check_rep=False))
+        check_vma=False))
 
 
 def make_node_mesh(n_devices: int = None) -> Mesh:

@@ -14,7 +14,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 STAGE_AXIS = "stage"
 
@@ -67,9 +66,8 @@ def gpipe_apply(stage_fn: Callable, stage_params, x_micro: jax.Array,
         return outputs
 
     pspec = jax.tree_util.tree_map(lambda _: P(STAGE_AXIS), stage_params)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(pspec, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
+                       out_specs=P(), check_vma=False)
     return fn(stage_params, x_micro)
 
 

@@ -6,11 +6,11 @@ instead of a per-request function-pointer dispatch, whole batches of
 nodes in VMEM tiles; per-destination histogram partials come out alongside
 so the caller can size the all-to-all without a second pass.
 
-``dest_histogram_kernel`` exposes the histogram stage on its own: the
-compacted exchange plan (burst_buffer.py) computes mixed-mode destinations
-by masked select and only needs the per-destination counts to lay out its
-budgeted send buffers.  Both kernels share the same one-hot block
-reduction (``_block_counts``).
+``dest_histogram2d_kernel`` exposes the histogram stage on its own: the
+compacted exchange plan (exchange_plan.py) computes mixed-mode
+destinations by masked select and only needs the per-(row, destination)
+counts to lay out its budgeted send buffers.  ``dest_histogram_kernel`` is
+its flat (n,) form.
 
 Integer hashing uses int32 ops (wrapping multiply == uint32 mul mod 2^32;
 we mask to 31 bits after every step so shifts stay logical).
@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 MASK31 = 0x7FFFFFFF
+LANES = 128
 
 
 def mix_hash_i32(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -95,11 +96,15 @@ def route_chunks_kernel(path_hash: jax.Array, chunk_id: jax.Array,
 
 
 def _hist_kernel(dest_ref, counts_ref, *, n_bins: int):
-    counts_ref[0] = _block_counts(dest_ref[...], n_bins)
+    """One source row: (1, q) destinations → (n_bins, 1) counts.
 
-
-def _hist2d_kernel(dest_ref, counts_ref, *, n_bins: int):
-    counts_ref[0] = _block_counts(dest_ref[0], n_bins)
+    Destinations stay on lanes and bins go down sublanes, so the one-hot
+    block is (n_bins, q) and the count is a lane reduction: no lane ↔
+    sublane relayout inside the kernel."""
+    d = dest_ref[...]                                        # (1, q)
+    bins = jax.lax.broadcasted_iota(jnp.int32, (n_bins, d.shape[1]), 0)
+    counts_ref[...] = (d == bins).astype(jnp.int32).sum(axis=1,
+                                                        keepdims=True)
 
 
 @functools.partial(jax.jit,
@@ -110,22 +115,18 @@ def dest_histogram_kernel(dest: jax.Array, *, n_bins: int,
     """(n,) int32 destinations → per-bin counts (n_bins,).
 
     Values outside [0, n_bins) — the plan's invalid-request sentinel — are
-    counted nowhere.  Padding uses -1 for the same reason.
+    counted nowhere.  The vector is cut into rows of ``block`` lanes
+    (padded with -1 for the same reason), histogrammed per row by
+    ``dest_histogram2d_kernel`` and summed.
     """
     n = dest.shape[0]
-    block = min(block, max(8, n))
-    nb = pl.cdiv(n, block)
+    block = min(block, max(LANES, pl.cdiv(n, LANES) * LANES))
+    nb = max(1, pl.cdiv(n, block))
     pad = nb * block - n
     if pad:
         dest = jnp.pad(dest, (0, pad), constant_values=-1)
-    counts = pl.pallas_call(
-        functools.partial(_hist_kernel, n_bins=n_bins),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1, n_bins), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, n_bins), jnp.int32),
-        interpret=interpret,
-    )(dest)
+    counts = dest_histogram2d_kernel(dest.reshape(nb, block), n_bins=n_bins,
+                                     interpret=interpret)
     return counts.sum(axis=0)
 
 
@@ -134,19 +135,24 @@ def dest_histogram2d_kernel(dest: jax.Array, *, n_bins: int,
                             interpret: bool = True) -> jax.Array:
     """(L, q) int32 destinations → per-row counts (L, n_bins).
 
-    Row-batched form of ``dest_histogram_kernel``: one grid step per source
-    row, so the one-hot block stays (q, n_bins) regardless of L.  This is
-    the histogram stage the compacted exchange plan runs per call — both to
+    One grid step per source row, so the one-hot block stays (n_bins, q)
+    regardless of L.  The rows are viewed as (L, 1, q) and the counts come
+    out as (L, n_bins, 1), so each block spans the full trailing two dims
+    of its array, as the TPU's (8, 128) tiling demands.  This is the
+    histogram stage the compacted exchange plan runs per call — both to
     lay out its budgeted send buffers and (host-side, on the same counts)
     to size the ragged per-destination budgets.  Out-of-range rows (the
     plan's invalid-request sentinel ``-1``) are counted nowhere.
     """
     L, q = dest.shape
-    return pl.pallas_call(
-        functools.partial(_hist2d_kernel, n_bins=n_bins),
+    if L == 0 or q == 0:
+        return jnp.zeros((L, n_bins), jnp.int32)
+    counts = pl.pallas_call(
+        functools.partial(_hist_kernel, n_bins=n_bins),
         grid=(L,),
-        in_specs=[pl.BlockSpec((1, q), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, n_bins), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((L, n_bins), jnp.int32),
+        in_specs=[pl.BlockSpec((None, 1, q), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((None, n_bins, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((L, n_bins, 1), jnp.int32),
         interpret=interpret,
-    )(dest)
+    )(dest.astype(jnp.int32).reshape(L, 1, q))
+    return counts.reshape(L, n_bins)

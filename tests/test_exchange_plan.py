@@ -547,3 +547,41 @@ def test_mesh_ragged_pinned_stream_and_telemetry_reduce():
                        capture_output=True, text=True, timeout=900,
                        cwd=str(ROOT))
     assert "MESH_PLAN_OK" in r.stdout, r.stdout + r.stderr
+
+
+SHARDED_PLAN_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
+    import sys; sys.path.insert(0, 'src')
+    import functools
+    import jax, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as PS
+    from repro.core import exchange_plan
+    from repro.core.mesh_engine import NODE_AXIS, make_node_mesh
+    from repro.kernels.chunk_router.chunk_router import (
+        dest_histogram2d_kernel)
+
+    rng = np.random.RandomState(0)
+    dest = rng.randint(0, 4, (4, 64)).astype(np.int32)
+    valid = rng.rand(4, 64) < 0.8
+    want = exchange_plan.plan_mesh_ragged_spec(dest, valid, 4)
+    # the chip's path: the Pallas histogram (interpreted here) planning
+    # from node-sharded outputs of a mesh op (a two-phase read's data_loc)
+    exchange_plan.histogram_rows2d = functools.partial(
+        dest_histogram2d_kernel, interpret=True)
+    shard = NamedSharding(make_node_mesh(4), PS(NODE_AXIS))
+    got = exchange_plan.plan_mesh_ragged_spec(
+        jax.device_put(dest, shard), jax.device_put(valid, shard), 4)
+    assert got == want, (got, want)
+    print('SHARDED_PLAN_OK')
+""")
+
+
+@pytest.mark.mesh
+def test_mesh_ragged_spec_from_node_sharded_inputs():
+    """The mesh planner runs the Pallas histogram on node-sharded inputs
+    (regression: the kernel was handed the sharded array and refused it)."""
+    r = subprocess.run([sys.executable, "-c", SHARDED_PLAN_SCRIPT],
+                       capture_output=True, text=True, timeout=600,
+                       cwd=str(ROOT))
+    assert "SHARDED_PLAN_OK" in r.stdout, r.stdout + r.stderr

@@ -52,21 +52,26 @@ def test_chunk_router_sweep(n, mode, nodes):
     assert int(c.sum()) == n
 
 
-@pytest.mark.parametrize("n,m,w", [(16, 16, 8), (100, 333, 16), (512, 64, 4)])
+@pytest.mark.parametrize("n,m,w", [(16, 16, 8), (100, 333, 16), (512, 64, 4),
+                                   (40, 37, 300)])
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
 def test_chunk_pack_sweep(n, m, w, dtype):
+    """The DMA gather in interpret mode at widths that are not a multiple
+    of 128 lanes (padded in the wrapper), with sentinel rows mixed in."""
     payload = jnp.asarray(RNG.randn(n, w) * 100, dtype)
-    idx = jnp.asarray(RNG.randint(0, n, m), jnp.int32)
-    out = pack_chunks(payload, idx)
-    np.testing.assert_array_equal(np.asarray(out),
+    idx = jnp.asarray(RNG.randint(-1, n, m), jnp.int32).at[0].set(-1)
+    out = np.asarray(pack_chunks(payload, idx, interpret=True))
+    np.testing.assert_array_equal(out,
                                   np.asarray(pack_chunks_ref(payload, idx)))
+    assert out.shape == (m, w) and out.dtype == payload.dtype
+    assert (out[np.asarray(idx) < 0] == 0).all()
 
 
 @pytest.mark.parametrize("m", [3, 17, 256, 259])
 def test_chunk_pack_sentinel_and_pad_path(m):
-    """Sentinel idx rows (-1) must come back zero, and the block padding of
-    ``idx`` must not silently gather row 0 into the padded tail (regression:
-    the kernel used to pad with 0).  Poison row 0 so any such leak is loud.
+    """Sentinel idx rows (-1) must come back zero and must never gather
+    row 0 (regression: an earlier kernel padded ``idx`` with 0).  Poison
+    row 0 so any such leak is loud.
     """
     n, w = 8, 4
     payload = jnp.full((n, w), 7777, jnp.int32).at[1:].set(
