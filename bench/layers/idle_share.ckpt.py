@@ -1,0 +1,7 @@
+"""device: percent of the traced window in which no op ran on the device,
+mean over chips (moves ``ckpt_GiBps``)."""
+from layer_common import idle_share
+
+
+def read(run):
+    return idle_share(run.trace)
