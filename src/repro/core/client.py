@@ -275,8 +275,9 @@ class BBClient:
             call then records a fenced ``client.*`` span, byte/carry/drop
             accounting lands in ``trace.metrics``, and selector picks are
             audited into ``trace.audit`` (see docs/observability.md).
-            ``None`` (default) compiles every instrumentation point down
-            to one branch.
+            With ``None`` (default) the same spans reach only a running
+            ``jax.profiler`` capture, unfenced, and cost two checks each
+            when none runs.
         """
         self.policy = as_policy(policy)
         self.backend = backend
@@ -379,7 +380,8 @@ class BBClient:
             # routed by its mode array but stored/searched by the policy's
             # paths — reject it here rather than silently losing data
             allowed = {int(m) for m in self.policy.modes_present()}
-            got = set(np.unique(np.asarray(req.mode)).tolist())
+            with obs.span("client.sync.mode", cat="client"):
+                got = set(np.unique(np.asarray(req.mode)).tolist())
             if not got <= allowed:
                 raise ValueError(
                     f"request modes {sorted(got - allowed)} not in this "
@@ -556,22 +558,18 @@ class BBClient:
         else:
             op = _stacked_migrate_for(self.policy.engine_key(), cfg,
                                       self.donate)
-        if self.obs is None:
-            self.state, moved, found_old = op(
-                self.state, jnp.asarray(path_hash),
-                jnp.asarray(chunk_id, jnp.int32), jnp.asarray(valid, bool),
-                old, new)
-            return moved, found_old
         with obs.activate(self.obs), \
                 obs.span("client.migrate", cat="client",
                          old_mode=int(old_mode), new_mode=int(new_mode)) as h:
-            self.state, moved, found_old = h.fence(op(
-                self.state, jnp.asarray(path_hash),
-                jnp.asarray(chunk_id, jnp.int32), jnp.asarray(valid, bool),
-                old, new))
-        m = self.obs.metrics
-        m.inc("migrate_calls_total", epoch=self.epoch)
-        m.inc("migrate_moved_total", float(np.asarray(moved).sum()))
+            with obs.span("client.dispatch", cat="client"):
+                out = op(self.state, jnp.asarray(path_hash),
+                         jnp.asarray(chunk_id, jnp.int32),
+                         jnp.asarray(valid, bool), old, new)
+            self.state, moved, found_old = h.fence(out)
+        if self.obs is not None:
+            m = self.obs.metrics
+            m.inc("migrate_calls_total", epoch=self.epoch)
+            m.inc("migrate_moved_total", float(np.asarray(moved).sum()))
         return moved, found_old
 
     # ---- per-call exchange dispatch -----------------------------------------
@@ -642,7 +640,9 @@ class BBClient:
             return 8
         align, left = self._align_state.get(q, (None, 0))
         if align is None or left <= 0:
-            align, left = self.telemetry.suggest_align(q), self._ALIGN_REFRESH
+            with obs.span("client.sync.align", cat="client"):
+                align = self.telemetry.suggest_align(q)
+            left = self._ALIGN_REFRESH
         self._align_state[q] = (align, left - 1)
         return align
 
@@ -662,49 +662,53 @@ class BBClient:
         the two-phase hybrid read's probed data-location array: with it,
         read destinations ARE computable here and the data round gets a
         measured plan; without it a hybrid read keeps the uniform
-        lossless plan for the whole call."""
-        q = ph.shape[1]
-        kind = self._select_kind(q)
-        if kind == "dense":
-            return bb.DENSE
-        cfg = self.exchange_config
-        if cfg.kind != "compacted":
-            cfg = dataclasses.replace(cfg, kind="compacted")
-        if not self.ragged or q == 0:
-            return cfg
-        N, client = self.n_nodes, self._client_ranks()
-        if op in ("write", "read") and cfg.budget is None:
-            if op == "read" and data_loc is None and \
-                    LayoutMode.HYBRID in self.policy.modes_present():
-                # hybrid read destinations come from the metadata phase
-                # (table state), which is invisible here — the two-phase
-                # path probes first and calls back in with data_loc
+        lossless plan for the whole call.  Runs in a ``client.plan``
+        span, routing in ``client.route``."""
+        with obs.span("client.plan", cat="client"):
+            q = ph.shape[1]
+            kind = self._select_kind(q)
+            if kind == "dense":
+                return bb.DENSE
+            cfg = self.exchange_config
+            if cfg.kind != "compacted":
+                cfg = dataclasses.replace(cfg, kind="compacted")
+            if not self.ragged or q == 0:
                 return cfg
-            dest = route_data(mode, N, ph, cid, client, data_loc=data_loc,
-                              xp=jnp)
-            cfg = dataclasses.replace(
-                cfg, data_spec=self._plan_spec(
-                    "data", dest, valid, 4 * (self.words + 3)))
-        if op in ("write", "meta") and cfg.meta_budget is None and \
-                cfg.budget is None:
-            # an explicit ``budget`` historically also caps the metadata
-            # exchange (see ``meta_budget``) — honour it rather than
-            # silently upgrading metadata to ragged sizing
-            owner = route_meta(mode, N, self.policy.n_md_servers, ph,
-                               client, xp=jnp)
-            cfg = dataclasses.replace(
-                cfg, meta_spec=self._plan_spec("meta", owner, valid,
-                                               4 * 8))
-        if cfg.pipeline and cfg.lossless and cfg.budget is not None:
-            # explicit uniform budgets skip ragged sizing, but the carry
-            # round need not pay the worst-case q − B width: measure the
-            # actual overflow histogram and cap the carry at the observed
-            # residual (same eager measurement the specs do)
-            hint = self._carry_hint(op, mode, ph, cid, valid, data_loc, q,
-                                    cfg)
-            if hint is not None:
-                cfg = dataclasses.replace(cfg, carry_budget_hint=hint)
-        return cfg
+            N, client = self.n_nodes, self._client_ranks()
+            if op in ("write", "read") and cfg.budget is None:
+                if op == "read" and data_loc is None and \
+                        LayoutMode.HYBRID in self.policy.modes_present():
+                    # hybrid read destinations come from the metadata phase
+                    # (table state), which is invisible here — the two-phase
+                    # path probes first and calls back in with data_loc
+                    return cfg
+                with obs.span("client.route", cat="client"):
+                    dest = route_data(mode, N, ph, cid, client,
+                                      data_loc=data_loc, xp=jnp)
+                cfg = dataclasses.replace(
+                    cfg, data_spec=self._plan_spec(
+                        "data", dest, valid, 4 * (self.words + 3)))
+            if op in ("write", "meta") and cfg.meta_budget is None and \
+                    cfg.budget is None:
+                # an explicit ``budget`` historically also caps the metadata
+                # exchange (see ``meta_budget``) — honour it rather than
+                # silently upgrading metadata to ragged sizing
+                with obs.span("client.route", cat="client"):
+                    owner = route_meta(mode, N, self.policy.n_md_servers, ph,
+                                       client, xp=jnp)
+                cfg = dataclasses.replace(
+                    cfg, meta_spec=self._plan_spec("meta", owner, valid,
+                                                   4 * 8))
+            if cfg.pipeline and cfg.lossless and cfg.budget is not None:
+                # explicit uniform budgets skip ragged sizing, but the carry
+                # round need not pay the worst-case q − B width: measure the
+                # actual overflow histogram and cap the carry at the observed
+                # residual (same eager measurement the specs do)
+                hint = self._carry_hint(op, mode, ph, cid, valid, data_loc, q,
+                                        cfg)
+                if hint is not None:
+                    cfg = dataclasses.replace(cfg, carry_budget_hint=hint)
+            return cfg
 
     def _carry_hint(self, op: str, mode, ph, cid, valid, data_loc,
                     q: int, cfg: bb.ExchangeConfig) -> Optional[int]:
@@ -725,27 +729,29 @@ class BBClient:
         # not pay eager destination routing just to discard it
         b_d = bb.data_budget(policy, q, cfg)
         b_m = bb.meta_budget(policy, q, cfg)
-        if b_d >= q and b_m >= q:
+        data = op in ("write", "read") and b_d < q
+        meta = op in ("write", "meta") and b_m < q
+        if data and op == "read" and data_loc is None and \
+                LayoutMode.HYBRID in policy.modes_present():
+            return None            # destinations live in table state
+        if not (data or meta):
             return None            # B = q everywhere: carry already elided
         # host-side measurement (numpy routing, like the spec planners):
-        # this sits on the hot request path, so it must not dispatch
-        # device work just to read a histogram
-        mode_h, ph_h = np.asarray(mode), np.asarray(ph)
-        ranks = np.asarray(self._client_ranks())
+        # this sits on the hot request path, so it reads the call's arrays
+        # to the host once and dispatches no device work
+        with obs.span("client.sync.carry_hint", cat="client"):
+            mode_h, ph_h, cid_h, loc_h, v = jax.device_get(
+                (mode, ph, cid if data else None,
+                 data_loc if data else None, valid))
+        ranks = np.arange(N, dtype=np.int32)[:, None]
         planes = []
-        if op in ("write", "read") and b_d < q:
-            if op == "read" and data_loc is None and \
-                    LayoutMode.HYBRID in policy.modes_present():
-                return None        # destinations live in table state
-            loc_h = None if data_loc is None else np.asarray(data_loc)
-            planes.append((route_data(mode_h, N, ph_h, np.asarray(cid),
-                                      ranks, data_loc=loc_h, xp=np), b_d))
-        if op in ("write", "meta") and b_m < q:
+        if data:
+            planes.append((route_data(mode_h, N, ph_h, cid_h, ranks,
+                                      data_loc=loc_h, xp=np), b_d))
+        if meta:
             planes.append((route_meta(mode_h, N, policy.n_md_servers,
                                       ph_h, ranks, xp=np), b_m))
-        if not planes:
-            return None
-        v = np.asarray(valid)
+        v = np.asarray(v)
         worst = 0
         for dest, b in planes:
             d = np.asarray(dest)
@@ -773,22 +779,38 @@ class BBClient:
             self._cache_put(self._mesh_ops, config, ops)
         return ops
 
+    # ---- engine entries -----------------------------------------------------
+    # One code path per op: ``_<op>_in`` plans and dispatches inside the
+    # open ``client.<op>`` span.  The public calls open that span around
+    # request resolution too; ``_write`` / ``_read`` / ``_meta`` take the
+    # state and resolved arrays explicitly (the benchmarks drive them).
     def _write(self, state, mode, ph, cid, payload, valid):
         """Engine write entry (state explicit — the benchmarks drive it)."""
-        if self.obs is None:
-            cfg = self._call_config("write", mode, ph, cid, valid)
-            return self._ops(cfg)[0](state, mode, ph, cid, payload, valid)
         with obs.activate(self.obs), \
                 obs.span("client.write", cat="client",
                          q=int(ph.shape[1])) as h:
-            cfg = self._call_config("write", mode, ph, cid, valid)
-            out = h.fence(
-                self._ops(cfg)[0](state, mode, ph, cid, payload, valid))
-        self._account("write", cfg, ph.shape[1], out, mode, ph, cid, valid)
+            return h.fence(self._write_in(state, mode, ph, cid, payload,
+                                          valid))
+
+    def _write_in(self, state, mode, ph, cid, payload, valid):
+        """Plan and dispatch one write."""
+        cfg = self._call_config("write", mode, ph, cid, valid)
+        with obs.span("client.dispatch", cat="client"):
+            out = self._ops(cfg)[0](state, mode, ph, cid, payload, valid)
+        if self.obs is not None:
+            self._account("write", cfg, ph.shape[1], out, mode, ph, cid,
+                          valid)
         return out
 
     def _read(self, state, mode, ph, cid, valid):
-        """Engine read entry (state explicit — the benchmarks drive it).
+        """Engine read entry (state explicit — the benchmarks drive it)."""
+        with obs.activate(self.obs), \
+                obs.span("client.read", cat="client",
+                         q=int(ph.shape[1])) as h:
+            return h.fence(self._read_in(state, mode, ph, cid, valid))
+
+    def _read_in(self, state, mode, ph, cid, valid):
+        """Plan and dispatch one read.
 
         Hybrid-capable ragged reads go two-phase: the metadata probe runs
         as its own jitted call, the resolved data locations size a
@@ -796,51 +818,48 @@ class BBClient:
         internal meta phase skipped — identical answers (the probe IS the
         same ``meta_op`` STAT), measured instead of worst-case budgets.
         """
-        if self.obs is None:
-            return self._read_impl(state, mode, ph, cid, valid)
-        with obs.activate(self.obs):
-            return self._read_impl(state, mode, ph, cid, valid)
-
-    def _read_impl(self, state, mode, ph, cid, valid):
-        """``_read`` body, run under the recorder activation (if any)."""
         q = ph.shape[1]
         if (self.two_phase and q > 0 and
                 LayoutMode.HYBRID in self.policy.modes_present() and
                 self.exchange_config.budget is None and
                 self._select_kind(q) == "compacted"):
             return self._read_two_phase(state, mode, ph, cid, valid)
-        with obs.span("client.read", cat="client", q=int(q)) as h:
-            cfg = self._call_config("read", mode, ph, cid, valid)
-            out = h.fence(self._ops(cfg)[1](state, mode, ph, cid, valid))
+        cfg = self._call_config("read", mode, ph, cid, valid)
+        with obs.span("client.dispatch", cat="client"):
+            out = self._ops(cfg)[1](state, mode, ph, cid, valid)
         if self.obs is not None:
             self._account("read", cfg, q, None, mode, ph, cid, valid)
         return out
 
     def _read_two_phase(self, state, mode, ph, cid, valid):
-        """Metadata probe → ragged data round (see ``_read``)."""
+        """Metadata probe → ragged data round (see ``_read_in``)."""
         shape = ph.shape
-        probe_valid = self._as_bool(valid) & (mode == LayoutMode.HYBRID)
-        ranks = jnp.broadcast_to(self._client_ranks(), shape)
-        if not bool(np.any(np.asarray(probe_valid))):
-            # no hybrid rows in THIS batch (e.g. an epoch-fallback re-read
-            # under a hashed old mode): skip the probe round entirely —
-            # every data destination resolves without table state
-            data_loc = ranks
-        else:
-            with obs.span("client.read.probe", cat="client") as h:
+        with obs.span("client.read.probe", cat="client") as h:
+            ranks = jnp.broadcast_to(self._client_ranks(), shape)
+            probe_valid = self._as_bool(valid) & (mode == LayoutMode.HYBRID)
+            with obs.span("client.sync.probe_mask", cat="client"):
+                any_hybrid = bool(np.any(np.asarray(probe_valid)))
+            if not any_hybrid:
+                # no hybrid rows in THIS batch (e.g. an epoch-fallback
+                # re-read under a hashed old mode): skip the probe round —
+                # every data destination resolves without table state
+                data_loc = ranks
+            else:
                 cfg_m = self._call_config("meta", mode, ph, None,
                                           probe_valid)
-                fm, loc = h.fence(
-                    self._probe_op(cfg_m)(state, mode, ph, probe_valid))
-            if self.obs is not None:
-                self._account("meta", cfg_m, shape[1], None, mode, ph,
-                              None, probe_valid)
-            data_loc = jnp.where(fm & (loc >= 0), loc, ranks)
+                with obs.span("client.dispatch", cat="client"):
+                    fm, loc = h.fence(self._probe_op(cfg_m)(
+                        state, mode, ph, probe_valid))
+                if self.obs is not None:
+                    self._account("meta", cfg_m, shape[1], None, mode, ph,
+                                  None, probe_valid)
+                data_loc = jnp.where(fm & (loc >= 0), loc, ranks)
         with obs.span("client.read.data", cat="client") as h:
             cfg = self._call_config("read", mode, ph, cid, valid,
                                     data_loc=data_loc)
-            out = h.fence(
-                self._ops(cfg)[3](state, mode, ph, cid, valid, data_loc))
+            with obs.span("client.dispatch", cat="client"):
+                out = h.fence(self._ops(cfg)[3](state, mode, ph, cid, valid,
+                                                data_loc))
         if self.obs is not None:
             self._account("read", cfg, shape[1], None, mode, ph, cid, valid)
         return out
@@ -863,17 +882,20 @@ class BBClient:
 
     def _meta(self, state, mode, op, ph, size, loc, valid):
         """Engine metadata entry (state explicit)."""
-        if self.obs is None:
-            cfg = self._call_config("meta", mode, ph, None, valid)
-            return self._ops(cfg)[2](state, mode, op, ph, size, loc, valid)
         with obs.activate(self.obs), \
                 obs.span("client.meta", cat="client",
                          q=int(ph.shape[1])) as h:
-            cfg = self._call_config("meta", mode, ph, None, valid)
-            out = h.fence(
-                self._ops(cfg)[2](state, mode, op, ph, size, loc, valid))
-        self._account("meta", cfg, ph.shape[1], out[0], mode, ph, None,
-                      valid)
+            return h.fence(self._meta_in(state, mode, op, ph, size, loc,
+                                         valid))
+
+    def _meta_in(self, state, mode, op, ph, size, loc, valid):
+        """Plan and dispatch one metadata call."""
+        cfg = self._call_config("meta", mode, ph, None, valid)
+        with obs.span("client.dispatch", cat="client"):
+            out = self._ops(cfg)[2](state, mode, op, ph, size, loc, valid)
+        if self.obs is not None:
+            self._account("meta", cfg, ph.shape[1], out[0], mode, ph, None,
+                          valid)
         return out
 
     # ---- traced-call accounting (tracing on only) ---------------------------
@@ -957,11 +979,17 @@ class BBClient:
     def write(self, req: BBRequest) -> "BBClient":
         """Write a batch of chunks; mutates the held state, returns self."""
         assert req.payload is not None, "write requires req.payload"
-        if self.telemetry is not None:
-            self._observe(req, "write")
-        self.state = self._write(self.state, self._modes(req), req.path_hash,
-                                 self._chunk_id(req), req.payload,
-                                 self._valid(req))
+        ph = req.path_hash
+        with obs.activate(self.obs), \
+                obs.span("client.write", cat="client",
+                         q=int(ph.shape[1])) as h:
+            if self.telemetry is not None:
+                self._observe(req, "write")
+            with obs.span("client.resolve", cat="client"):
+                mode, cid, valid = (self._modes(req), self._chunk_id(req),
+                                    self._valid(req))
+            self.state = h.fence(self._write_in(self.state, mode, ph, cid,
+                                                req.payload, valid))
         return self
 
     def read(self, req: BBRequest) -> Tuple[jax.Array, jax.Array]:
@@ -971,22 +999,28 @@ class BBClient:
         migrating scope are re-issued under the old mode — a chunk the
         watermark hasn't reached yet is served from its old placement.
         """
-        if self.telemetry is not None:
-            self._observe(req, "read")
-        payload, found = self._read(self.state, self._modes(req),
-                                    req.path_hash, self._chunk_id(req),
+        ph = req.path_hash
+        with obs.activate(self.obs), \
+                obs.span("client.read", cat="client",
+                         q=int(ph.shape[1])) as h:
+            if self.telemetry is not None:
+                self._observe(req, "read")
+            with obs.span("client.resolve", cat="client"):
+                mode, cid, valid = (self._modes(req), self._chunk_id(req),
                                     self._valid(req))
-        fb = self.fallback
-        if fb is not None and req.scope_hash is not None:
-            miss = (np.asarray(self._valid(req)) & ~np.asarray(found) &
-                    (self._scope_hashes(req) == fb.scope_hash))
-            if miss.any():
-                old = jnp.full(req.path_hash.shape, fb.old_mode, jnp.int32)
-                p2, f2 = self._read(self.state, old, req.path_hash,
-                                    self._chunk_id(req), jnp.asarray(miss))
-                payload = jnp.where(f2[..., None], p2, payload)
-                found = jnp.logical_or(found, f2)
-        return payload, found
+            payload, found = self._read_in(self.state, mode, ph, cid, valid)
+            fb = self.fallback
+            if fb is not None and req.scope_hash is not None:
+                with obs.span("client.sync.fallback", cat="client"):
+                    miss = (np.asarray(valid) & ~np.asarray(found) &
+                            (self._scope_hashes(req) == fb.scope_hash))
+                if miss.any():
+                    old = jnp.full(ph.shape, fb.old_mode, jnp.int32)
+                    p2, f2 = self._read_in(self.state, old, ph, cid,
+                                           jnp.asarray(miss))
+                    payload = jnp.where(f2[..., None], p2, payload)
+                    found = jnp.logical_or(found, f2)
+            return h.fence((payload, found))
 
     # ---- metadata plane -----------------------------------------------------
     def _meta_call(self, opcode: int, req: BBRequest, mode=None, valid=None):
@@ -994,18 +1028,22 @@ class BBClient:
 
         ``mode``/``valid`` override the request's resolution — the
         dual-epoch retries pass the old-mode array with a miss mask."""
-        shape = req.path_hash.shape
-        op = jnp.full(shape, opcode, jnp.int32)
-        size = (jnp.zeros(shape, jnp.int32) if req.size is None
-                else jnp.asarray(req.size, jnp.int32))
-        loc = (jnp.full(shape, -1, jnp.int32) if req.loc is None
-               else jnp.asarray(req.loc, jnp.int32))
-        if mode is None and self.telemetry is not None:
-            self._observe(req, "meta")
-        self.state, found, r_size, r_loc = self._meta(
-            self.state, self._modes(req) if mode is None else mode, op,
-            req.path_hash, size, loc,
-            self._valid(req) if valid is None else valid)
+        ph = req.path_hash
+        shape = ph.shape
+        with obs.activate(self.obs), \
+                obs.span("client.meta", cat="client", q=int(shape[1])) as h:
+            if mode is None and self.telemetry is not None:
+                self._observe(req, "meta")
+            with obs.span("client.resolve", cat="client"):
+                op = jnp.full(shape, opcode, jnp.int32)
+                size = (jnp.zeros(shape, jnp.int32) if req.size is None
+                        else jnp.asarray(req.size, jnp.int32))
+                loc = (jnp.full(shape, -1, jnp.int32) if req.loc is None
+                       else jnp.asarray(req.loc, jnp.int32))
+                mode = self._modes(req) if mode is None else mode
+                valid = self._valid(req) if valid is None else valid
+            self.state, found, r_size, r_loc = h.fence(self._meta_in(
+                self.state, mode, op, ph, size, loc, valid))
         return found, r_size, r_loc
 
     def _epoch_miss(self, req: BBRequest, found) -> Optional[np.ndarray]:
@@ -1013,8 +1051,9 @@ class BBClient:
         fb = self.fallback
         if fb is None or req.scope_hash is None:
             return None
-        miss = (np.asarray(self._valid(req)) & ~np.asarray(found) &
-                (self._scope_hashes(req) == fb.scope_hash))
+        with obs.span("client.sync.fallback", cat="client"):
+            miss = (np.asarray(self._valid(req)) & ~np.asarray(found) &
+                    (self._scope_hashes(req) == fb.scope_hash))
         return miss if miss.any() else None
 
     def create(self, req: BBRequest) -> jax.Array:
@@ -1054,8 +1093,9 @@ class BBClient:
                 self._files.get(int(sh[i, j]), {}).pop(int(ph[i, j]), None)
         fb = self.fallback
         if fb is not None and req.scope_hash is not None:
-            in_scope = (np.asarray(self._valid(req)) &
-                        (self._scope_hashes(req) == fb.scope_hash))
+            with obs.span("client.sync.fallback", cat="client"):
+                in_scope = (np.asarray(self._valid(req)) &
+                            (self._scope_hashes(req) == fb.scope_hash))
             if in_scope.any():
                 old = jnp.full(req.path_hash.shape, fb.old_mode, jnp.int32)
                 f2, _, _ = self._meta_call(bb.OP_REMOVE, req, mode=old,

@@ -316,8 +316,12 @@ def plan_ragged_spec(dest: jax.Array, valid: jax.Array, n_nodes: int,
                   n_nodes)
     q = d.shape[1]
     counts = histogram_rows2d(d, n_bins=n_nodes + 1)[:, :n_nodes]
-    budgets = np.asarray(counts).max(axis=0) if counts.shape[0] else \
-        np.zeros(n_nodes, np.int64)
+    if counts.shape[0]:
+        with obs.span("client.sync.spec", cat="client"):
+            counts = np.asarray(counts)
+        budgets = counts.max(axis=0)
+    else:
+        budgets = np.zeros(n_nodes, np.int64)
     budgets = _quantize(budgets, q, align, floor)
     return RaggedSpec(tuple(int(b) for b in budgets))
 
@@ -354,10 +358,13 @@ def plan_mesh_ragged_spec(dest: jax.Array, valid: jax.Array, n_nodes: int,
     from repro.core import exchange_select
     # host copies first: dest may be the node-sharded output of a mesh op,
     # and a Pallas kernel cannot take a sharded array outside shard_map
-    d = jnp.asarray(np.where(np.asarray(valid),
-                             np.asarray(dest).astype(np.int32), n_nodes))
+    with obs.span("client.sync.spec", cat="client"):
+        valid, dest = np.asarray(valid), np.asarray(dest)
+    d = jnp.asarray(np.where(valid, dest.astype(np.int32), n_nodes))
     q = d.shape[1]
-    hist = np.asarray(histogram_rows2d(d, n_bins=n_nodes + 1)[:, :n_nodes])
+    hist = histogram_rows2d(d, n_bins=n_nodes + 1)[:, :n_nodes]
+    with obs.span("client.sync.spec", cat="client"):
+        hist = np.asarray(hist)
     if hist.shape[0] == 0:
         hist = np.zeros((1, n_nodes), np.int64)
     budgets = _quantize(hist.max(axis=0), q, align, floor)
@@ -1238,8 +1245,7 @@ def fused_send(ex_d: Executor, plan_d: ExchangePlan, fields_d: jax.Array,
     the receive split constant index maps (``_fused_pack_cols`` /
     ``_fused_recv_cols``).
     """
-    if obs.current_recorder() is not None:
-        exchange = _spanned_collective(exchange, "exchange.all_to_all")
+    exchange = _spanned_collective(exchange, "exchange.all_to_all")
     if isinstance(ex_d, UniformExecutor):
         buf = jnp.concatenate(
             [_compact_gather(fields_d, plan_d.send_idx),
@@ -1269,9 +1275,9 @@ def fused_send(ex_d: Executor, plan_d: ExchangePlan, fields_d: jax.Array,
 def _spanned_collective(fn: Callable, name: str) -> Callable:
     """Wrap a collective hook so each trace-time call records a span.
 
-    Only installed when a recorder is active: the wrapper exists for the
-    duration of one ``run_exchange`` trace, so span identity never leaks
-    into jit cache keys (the collective itself is unchanged).
+    The wrapper exists for the duration of one ``run_exchange`` trace, so
+    span identity never leaks into jit cache keys (the collective itself
+    is unchanged).
     """
     def wrapped(*args, **kwargs):
         with obs.span(name, cat="trace"):
@@ -1311,17 +1317,17 @@ def run_exchange(role: str, policy, config: ExchangeConfig,
     everywhere; ``client`` carries the local rows' global ranks for the
     shift-round executor.
 
-    When a flight recorder is active (``obs.activate``), each pipeline
-    stage records a ``cat="trace"`` span — ``exchange.plan`` →
-    ``exchange.pack`` (wrapping the ``exchange.all_to_all`` /
-    ``exchange.ppermute`` collective spans) → ``exchange.apply`` →
-    ``exchange.collect`` → ``exchange.carry``.  This code runs while jax
-    is *tracing*, so the spans fire once per specialization and measure
-    plan/lowering cost, giving the recording its nested structure.
+    When a flight recorder is active (``obs.activate``) or a profiler
+    capture runs, each pipeline stage records a ``cat="trace"`` span —
+    ``exchange.plan`` → ``exchange.pack`` (wrapping the
+    ``exchange.all_to_all`` / ``exchange.ppermute`` collective spans) →
+    ``exchange.apply`` → ``exchange.collect`` → ``exchange.carry``.  This
+    code runs while jax is *tracing*, so the spans fire once per
+    specialization and measure plan/lowering cost, giving the recording
+    its nested structure.
     """
-    if obs.current_recorder() is not None:
-        exchange = _spanned_collective(exchange, "exchange.all_to_all")
-        shift = _spanned_collective(shift, "exchange.ppermute")
+    exchange = _spanned_collective(exchange, "exchange.all_to_all")
+    shift = _spanned_collective(shift, "exchange.ppermute")
     with obs.span("exchange.plan", cat="trace", role=role,
                   kind=config.kind):
         ex = build_executor(role, policy, dest.shape[1], config)

@@ -4,7 +4,8 @@ Public surface of ``repro.core.obs`` — the single source of timing
 truth for the exchange/adapt pipeline (see ``docs/observability.md``):
 
 * :class:`TraceRecorder` / :func:`span` / :func:`activate` — bounded
-  span ring with ``block_until_ready``-fenced timing
+  span ring with ``block_until_ready``-fenced timing, and a
+  ``jax.profiler`` annotation per span while a capture runs
   (:mod:`~repro.core.obs.recorder`);
 * :class:`MetricsRegistry` — counters/gauges/histograms
   (:mod:`~repro.core.obs.metrics`);
@@ -15,8 +16,8 @@ truth for the exchange/adapt pipeline (see ``docs/observability.md``):
   export and the shared bench provenance block
   (:mod:`~repro.core.obs.export`).
 
-Everything is off by default: with no active recorder each
-instrumentation point costs one truthiness check.
+Everything is off by default: with no active recorder and no profiler
+capture each instrumentation point costs two checks.
 """
 from repro.core.obs.audit import (
     EVIDENCE_GRADES,
@@ -36,7 +37,6 @@ from repro.core.obs.export import (
 from repro.core.obs.metrics import (
     MetricsRegistry,
     metric_key,
-    overlap_efficiency,
 )
 from repro.core.obs.recorder import (
     Span,
@@ -66,7 +66,6 @@ __all__ = [
     "current_metrics",
     "current_recorder",
     "metric_key",
-    "overlap_efficiency",
     "provenance_meta",
     "record_decision",
     "recording_dict",

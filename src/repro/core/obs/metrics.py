@@ -33,25 +33,6 @@ def metric_key(name: str, labels: Dict[str, object]) -> str:
     return f"{name}{{{inner}}}"
 
 
-def overlap_efficiency(sync_us: float, pipelined_us: float,
-                       lower_bound_us: float) -> float:
-    """How much of the pipelining headroom a measured round captured.
-
-    ``1.0`` means the pipelined round reached the fabric model's
-    pure-bytes lower bound (every µs of gather latency hidden behind the
-    collective); ``0.0`` means it did no better than the synchronous
-    round.  Clamped to [0, 1] so regressions (pipelined slower than
-    sync) and fits whose lower bound exceeds the sync time (degenerate
-    headroom) stay plottable rather than exploding the scale — in the
-    degenerate case the round scores 1.0 when pipelining did not hurt
-    and 0.0 when it did.
-    """
-    headroom = sync_us - lower_bound_us
-    if headroom <= 0:
-        return 1.0 if pipelined_us <= sync_us else 0.0
-    return min(1.0, max(0.0, (sync_us - pipelined_us) / headroom))
-
-
 class MetricsRegistry:
     """Counters, gauges and histograms for one recording.
 

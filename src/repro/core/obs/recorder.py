@@ -3,9 +3,13 @@
 A :class:`TraceRecorder` owns three planes of one recording: the span
 ring (this module), a :class:`~.metrics.MetricsRegistry` and a
 :class:`~.audit.DecisionAudit`.  Passing one to ``BBClient(trace=...)``
-turns the whole exchange/adapt pipeline into an instrumented run; with
-no recorder every instrumentation point is a dict lookup and a branch,
-cheap enough to leave compiled in everywhere.
+turns the whole exchange/adapt pipeline into an instrumented run.
+
+Every :func:`span` also enters a ``jax.profiler.TraceAnnotation`` of the
+same name while a profiler capture runs, recorder or not, so the
+program's spans land on the capture's ``/host:CPU`` plane on the same
+clock as the device's events.  With no capture the annotation is skipped
+after one check; the profiler path never fences.
 
 Two span categories exist because jax splits every computation into a
 trace/compile phase and an execute phase:
@@ -14,14 +18,17 @@ trace/compile phase and an execute phase:
   (``run_exchange``, the burst-buffer entry points).  They fire once
   per specialization and measure plan/lowering cost — and, crucially,
   they give the recording its nested plan → pack → all_to_all/ppermute
-  → apply → carry structure.
+  → apply → carry structure.  In a profiler capture of warmed calls
+  one of them marks a retrace.
 * host-side spans (``cat="client"``, ``"adapt"``, ...) wrap dispatch
   sites.  Wall-clocking a jax dispatch without synchronizing measures
   only the async enqueue, so a span may register a **fence** value:
   at span exit the recorder calls ``jax.block_until_ready`` on its
   leaves *before* taking the end timestamp.  That is the one correct
-  way to time jit work, and ``tools/repo_lint.py`` now rejects the
-  unfenced pattern everywhere else.
+  way to time jit work with the recorder's own clock, and
+  ``tools/repo_lint.py`` rejects the unfenced pattern everywhere else.
+  The fence applies only with an active recorder: a profiler capture
+  times the device itself.
 
 Activation is dynamically scoped: ``with activate(rec): ...`` pushes
 ``rec`` on a stack consulted by the module-level :func:`span` /
@@ -37,6 +44,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.obs.audit import DecisionAudit
 from repro.core.obs.metrics import MetricsRegistry
@@ -213,36 +222,57 @@ class _NullHandle(SpanHandle):
 _NULL_HANDLE = _NullHandle()
 
 
-@contextlib.contextmanager
-def span(name: str, cat: str = "bb", **attrs: object
-         ) -> Iterator[SpanHandle]:
-    """Span on the *current* recorder; near-free no-op when none is active.
+class _Span:
+    """The context manager :func:`span` returns (a class, not a generator:
+    it runs on every client call, tracing or not)."""
 
-    The no-op path yields a shared inert handle (its ``set``/``fence``
-    still work, they just record nothing), so instrumented code never
-    branches on whether tracing is on.
+    __slots__ = ("name", "cat", "attrs", "_ann", "_rec")
+
+    def __init__(self, name: str, cat: str, attrs: Dict[str, object]):
+        self.name, self.cat, self.attrs = name, cat, attrs
+        self._ann = self._rec = None
+
+    def __enter__(self) -> SpanHandle:
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
+        if not _ACTIVE:
+            return _NULL_HANDLE
+        self._rec = _ACTIVE[-1].span(self.name, cat=self.cat, **self.attrs)
+        return self._rec.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        try:
+            if self._rec is not None:
+                self._rec.__exit__(*exc)
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
+        return False
+
+
+def span(name: str, cat: str = "bb", **attrs: object) -> _Span:
+    """Span on the *current* recorder and the profiler, when either runs.
+
+    With no active recorder the span yields a shared inert handle (its
+    ``set``/``fence`` still work, they just record nothing), so
+    instrumented code never branches on whether tracing is on; with no
+    profiler capture either, entering and leaving it costs two checks.
     """
-    if not _ACTIVE:
-        yield _NULL_HANDLE
-        return
-    with _ACTIVE[-1].span(name, cat=cat, **attrs) as handle:
-        yield handle
+    return _Span(name, cat, attrs)
 
 
 def trace_span(name: str, cat: str = "trace"):
-    """Decorator: wrap a function in a :func:`span` when tracing is on.
+    """Decorator: wrap a function in a :func:`span`.
 
     Used on the burst-buffer entry points, which execute during jit
     *tracing* — the span fires once per specialization and nests under
-    the dispatching client span.  With no active recorder the wrapper
-    is a single truthiness check.
+    the dispatching client span.
     """
     def deco(fn):
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
-            if not _ACTIVE:
-                return fn(*args, **kwargs)
-            with _ACTIVE[-1].span(name, cat=cat):
+            with span(name, cat=cat):
                 return fn(*args, **kwargs)
         return wrapped
     return deco
