@@ -890,3 +890,28 @@ def migrate_rows(state: BBState, layout, path_hash: jax.Array,
     state = _tombstone_broadcast(state, keys, valid & found_old, keep,
                                  exchange, N, node_ids)
     return state, moved, found_old
+
+
+class EngineOps(tuple):
+    """The jitted ``(write, read, meta, read_loc)`` programs of one config.
+
+    These four take the state without donating it: a caller may run them
+    again on the state it passed.  ``owned`` holds the same four with
+    write and meta donating the state (argument 0), so XLA updates the
+    node tables in place instead of copying every table into a fresh
+    output, and deletes the caller's arrays.  Only a caller that rebinds
+    its state to the result may run ``owned``.  The reads return no state
+    and never donate; both tuples share them.
+    """
+
+    owned: Tuple
+
+
+def jit_engine_ops(write: Callable, read: Callable, meta: Callable,
+                   read_loc: Callable) -> EngineOps:
+    """Jit the four engine programs of one config, with ``owned`` twins."""
+    ops = EngineOps((jax.jit(write), jax.jit(read), jax.jit(meta),
+                     jax.jit(read_loc)))
+    ops.owned = (jax.jit(write, donate_argnums=0), ops[1],
+                 jax.jit(meta, donate_argnums=0), ops[3])
+    return ops

@@ -116,8 +116,7 @@ class BBRequest:
 
 
 @functools.lru_cache(maxsize=256)
-def _stacked_ops_for(engine_key, config: bb.ExchangeConfig,
-                     donate: bool = False):
+def _stacked_ops_for(engine_key, config: bb.ExchangeConfig) -> bb.EngineOps:
     """Jitted stacked ops, cached per engine specialization.
 
     Keyed on ``policy.engine_key()`` (not the policy object) × the full
@@ -125,18 +124,11 @@ def _stacked_ops_for(engine_key, config: bb.ExchangeConfig,
     client whose policy traces to the same program shares one set of
     jitted ops and XLA's trace cache.  Ragged configs carry their
     ``RaggedSpec`` in the key, so each measured traffic shape gets (and
-    re-uses) its own specialization.
-
-    ``donate=True`` marks the state argument of the *mutating* ops
-    (write / meta) as donated, so XLA reuses the input tables in place
-    instead of allocating a fresh copy per round.  The donated input is
-    DELETED after the call — callers must rebind (the public client API
-    does; raw ``client._write(client.state, ...)`` loops must not turn
-    donation on).  Read ops never donate.  The flag is part of the cache
-    key, so donating and non-donating clients get separate jits.
+    re-uses) its own specialization.  The entry holds both the
+    state-keeping ops and their donating ``owned`` twins (``EngineOps``);
+    only the ones a caller runs are compiled.
     """
     policy = LayoutPolicy.for_engine_key(engine_key)
-    dargs = (0,) if donate else ()
 
     def _write(state, mode, ph, cid, payload, valid):
         return bb.forward_write(state, policy, ph, cid, payload, valid,
@@ -154,15 +146,13 @@ def _stacked_ops_for(engine_key, config: bb.ExchangeConfig,
         return bb.forward_read(state, policy, ph, cid, valid, mode=mode,
                                config=config, data_loc=data_loc)
 
-    return (jax.jit(_write, donate_argnums=dargs), jax.jit(_read),
-            jax.jit(_meta, donate_argnums=dargs), jax.jit(_read_loc))
+    return bb.jit_engine_ops(_write, _read, _meta, _read_loc)
 
 
 def _build_stacked_ops(policy: LayoutPolicy,
-                       config: bb.ExchangeConfig = bb.DENSE,
-                       donate: bool = False):
+                       config: bb.ExchangeConfig = bb.DENSE) -> bb.EngineOps:
     """Resolve ``policy`` to its engine key and fetch the cached ops."""
-    return _stacked_ops_for(policy.engine_key(), config, donate)
+    return _stacked_ops_for(policy.engine_key(), config)
 
 
 @functools.lru_cache(maxsize=256)
@@ -189,16 +179,18 @@ def _stacked_probe_for(engine_key, config: bb.ExchangeConfig):
 
 
 @functools.lru_cache(maxsize=64)
-def _stacked_migrate_for(engine_key, config: bb.ExchangeConfig,
-                         donate: bool = False):
-    """Jitted stacked ``migrate_rows``, cached like ``_stacked_ops_for``."""
+def _stacked_migrate_for(engine_key, config: bb.ExchangeConfig):
+    """Jitted stacked ``migrate_rows``, cached like ``_stacked_ops_for``.
+
+    Its only caller, ``BBClient.migrate_rows``, rebinds the client's
+    state to the result, so the state is always donated."""
     policy = LayoutPolicy.for_engine_key(engine_key)
 
     def _migrate(state, ph, cid, valid, old_mode, new_mode):
         return bb.migrate_rows(state, policy, ph, cid, valid, old_mode,
                                new_mode, config=config)
 
-    return jax.jit(_migrate, donate_argnums=(0,) if donate else ())
+    return jax.jit(_migrate, donate_argnums=0)
 
 
 class BBClient:
@@ -211,6 +203,18 @@ class BBClient:
     >>> req = client.encode(paths, chunk_id=cids, payload=chunks)
     >>> client.write(req)
     >>> out, found = client.read(req)
+
+    The client owns ``self.state``.  Its mutating calls (``write``,
+    ``create``, ``stat``, ``remove``, ``migrate_rows``) donate the state to
+    the engine program and rebind ``self.state`` to the result, so the
+    node tables are updated in place and the arrays of the old state are
+    deleted.  Keep no reference to ``client.state`` across such a call:
+    read it again afterwards.  A state adopted through ``state=`` is handed
+    over the same way; the first mutating call deletes the caller's
+    arrays.  ``read`` and the two-phase probe return no state and donate
+    nothing.  The state-explicit entries (``_write``, ``_read``, ``_meta``
+    and the programs of ``_ops``) take the state from their caller, who
+    may reuse it, and never donate.
     """
 
     def __init__(self, policy, backend: Union[str, "jax.sharding.Mesh"]
@@ -220,7 +224,7 @@ class BBClient:
                  meta_budget: Optional[int] = None, capacity: float = 2.0,
                  lossless: bool = True, ragged: bool = True,
                  two_phase: bool = True, pipeline: bool = True,
-                 donate: bool = False, telemetry: bool = False,
+                 telemetry: bool = False,
                  trace: Optional[obs.TraceRecorder] = None):
         """Build a client holding fresh (or adopted) node tables.
 
@@ -231,6 +235,7 @@ class BBClient:
           cap/words/mcap: per-node data-slot count, chunk width (int32
             words) and metadata-slot count of the held ``BBState``.
           state: adopt an existing ``BBState`` instead of ``init_state``.
+            It is handed over: the first mutating call deletes its arrays.
           exchange: ``"auto"`` (default — pick dense vs compacted per call
             from the measured benchmark crossover), ``"dense"``, or
             ``"compacted"``.
@@ -257,12 +262,6 @@ class BBClient:
             hoisted carry plans, and measured carry-width hints.  Every
             result stays bit-for-bit identical; ``False`` restores the
             synchronous PR-5 call structure (the A/B baseline).
-          donate: donate the state argument of mutating jitted ops
-            (write / meta / migrate), reusing the node tables in place
-            instead of reallocating per call.  Off by default because
-            donation DELETES the input state — safe through the public
-            API (which rebinds ``self.state``), unsafe for raw
-            ``client._write(client.state, ...)`` replay loops.
           telemetry: accumulate per-scope intent counters on every call
             (jit-side — see repro.core.adapt.telemetry) and maintain the
             host-side write registry the ``LiveMigrator`` builds its
@@ -288,7 +287,6 @@ class BBClient:
                              f"{EXCHANGE_KINDS}")
         self.exchange_mode = exchange
         self.pipeline = bool(pipeline)
-        self.donate = bool(donate)
         self.exchange_config = bb.ExchangeConfig(
             kind=exchange if exchange != "auto" else "compacted",
             budget=budget, meta_budget=meta_budget, capacity=capacity,
@@ -552,12 +550,10 @@ class BBClient:
             op = self._mesh_migrate.get(cfg)
             if op is None:
                 from repro.core.mesh_engine import build_mesh_migrate
-                op = build_mesh_migrate(self.backend, self.policy, cfg,
-                                        donate=self.donate)
+                op = build_mesh_migrate(self.backend, self.policy, cfg)
                 self._cache_put(self._mesh_migrate, cfg, op)
         else:
-            op = _stacked_migrate_for(self.policy.engine_key(), cfg,
-                                      self.donate)
+            op = _stacked_migrate_for(self.policy.engine_key(), cfg)
         with obs.activate(self.obs), \
                 obs.span("client.migrate", cat="client",
                          old_mode=int(old_mode), new_mode=int(new_mode)) as h:
@@ -766,24 +762,35 @@ class BBClient:
             self._hint_floor[q] = floor = hint
         return floor
 
-    def _ops(self, config: bb.ExchangeConfig) -> Tuple:
-        """(write, read, meta, read_loc) jitted ops for one config."""
+    def _ops(self, config: bb.ExchangeConfig) -> bb.EngineOps:
+        """(write, read, meta, read_loc) jitted ops for one config; they
+        keep the state they are given (``owned`` holds the donating
+        twins)."""
         if not self._is_mesh:
-            return _stacked_ops_for(self.policy.engine_key(), config,
-                                    self.donate)
+            return _stacked_ops_for(self.policy.engine_key(), config)
         ops = self._mesh_ops.get(config)
         if ops is None:
             from repro.core.mesh_engine import build_mesh_ops
-            ops = build_mesh_ops(self.backend, self.policy, config,
-                                 donate=self.donate)
+            ops = build_mesh_ops(self.backend, self.policy, config)
             self._cache_put(self._mesh_ops, config, ops)
         return ops
+
+    def _ops_for(self, config: bb.ExchangeConfig, owned: bool) -> Tuple:
+        """The ops a call runs: the donating ``owned`` twins when the call
+        owns the state it passes (it rebinds ``self.state`` to the
+        result), else ``_ops(config)``.  A stand-in for ``_ops`` that
+        returns a plain tuple (an instrumenting wrapper) has no twins, and
+        its ops run as given."""
+        ops = self._ops(config)
+        return getattr(ops, "owned", ops) if owned else ops
 
     # ---- engine entries -----------------------------------------------------
     # One code path per op: ``_<op>_in`` plans and dispatches inside the
     # open ``client.<op>`` span.  The public calls open that span around
-    # request resolution too; ``_write`` / ``_read`` / ``_meta`` take the
-    # state and resolved arrays explicitly (the benchmarks drive them).
+    # request resolution too, and pass ``owned=True``: they hand
+    # ``self.state`` over and rebind it.  ``_write`` / ``_read`` / ``_meta``
+    # take the state and resolved arrays explicitly (the benchmarks drive
+    # them) and leave the caller's state alive.
     def _write(self, state, mode, ph, cid, payload, valid):
         """Engine write entry (state explicit — the benchmarks drive it)."""
         with obs.activate(self.obs), \
@@ -792,11 +799,12 @@ class BBClient:
             return h.fence(self._write_in(state, mode, ph, cid, payload,
                                           valid))
 
-    def _write_in(self, state, mode, ph, cid, payload, valid):
-        """Plan and dispatch one write."""
+    def _write_in(self, state, mode, ph, cid, payload, valid, owned=False):
+        """Plan and dispatch one write (donating ``state`` if ``owned``)."""
         cfg = self._call_config("write", mode, ph, cid, valid)
         with obs.span("client.dispatch", cat="client"):
-            out = self._ops(cfg)[0](state, mode, ph, cid, payload, valid)
+            out = self._ops_for(cfg, owned)[0](state, mode, ph, cid,
+                                                payload, valid)
         if self.obs is not None:
             self._account("write", cfg, ph.shape[1], out, mode, ph, cid,
                           valid)
@@ -888,11 +896,13 @@ class BBClient:
             return h.fence(self._meta_in(state, mode, op, ph, size, loc,
                                          valid))
 
-    def _meta_in(self, state, mode, op, ph, size, loc, valid):
-        """Plan and dispatch one metadata call."""
+    def _meta_in(self, state, mode, op, ph, size, loc, valid, owned=False):
+        """Plan and dispatch one metadata call (donating ``state`` if
+        ``owned``)."""
         cfg = self._call_config("meta", mode, ph, None, valid)
         with obs.span("client.dispatch", cat="client"):
-            out = self._ops(cfg)[2](state, mode, op, ph, size, loc, valid)
+            out = self._ops_for(cfg, owned)[2](state, mode, op, ph, size,
+                                                loc, valid)
         if self.obs is not None:
             self._account("meta", cfg, ph.shape[1], out[0], mode, ph, None,
                           valid)
@@ -989,7 +999,8 @@ class BBClient:
                 mode, cid, valid = (self._modes(req), self._chunk_id(req),
                                     self._valid(req))
             self.state = h.fence(self._write_in(self.state, mode, ph, cid,
-                                                req.payload, valid))
+                                                req.payload, valid,
+                                                owned=True))
         return self
 
     def read(self, req: BBRequest) -> Tuple[jax.Array, jax.Array]:
@@ -1043,18 +1054,18 @@ class BBClient:
                 mode = self._modes(req) if mode is None else mode
                 valid = self._valid(req) if valid is None else valid
             self.state, found, r_size, r_loc = h.fence(self._meta_in(
-                self.state, mode, op, ph, size, loc, valid))
+                self.state, mode, op, ph, size, loc, valid, owned=True))
         return found, r_size, r_loc
 
-    def _epoch_miss(self, req: BBRequest, found) -> Optional[np.ndarray]:
-        """Migrating-scope rows the new epoch missed (None if no retry)."""
+    def _epoch_rows(self, req: BBRequest) -> Optional[np.ndarray]:
+        """Valid rows of the migrating scope (None if there is none)."""
         fb = self.fallback
         if fb is None or req.scope_hash is None:
             return None
         with obs.span("client.sync.fallback", cat="client"):
-            miss = (np.asarray(self._valid(req)) & ~np.asarray(found) &
+            rows = (np.asarray(self._valid(req)) &
                     (self._scope_hashes(req) == fb.scope_hash))
-        return miss if miss.any() else None
+        return rows if rows.any() else None
 
     def create(self, req: BBRequest) -> jax.Array:
         """Create file entries (idempotent) → found mask."""
@@ -1064,18 +1075,22 @@ class BBClient:
     def stat(self, req: BBRequest) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Stat file entries → (found, size, data_location_rank).
 
-        Dual-epoch during a relayout: entries whose file the watermark
-        hasn't reached are still served by the old-mode owner."""
+        Dual-epoch during a relayout: entries of the migrating scope are
+        also stat'ed under the old mode, so a file the watermark hasn't
+        reached is still served by its old-mode owner.  A file written
+        during the relayout has an entry in each epoch until its
+        installment merges them; its size is the larger of the two (sizes
+        only grow), its location the new epoch's."""
         found, size, loc = self._meta_call(bb.OP_STAT, req)
-        miss = self._epoch_miss(req, found)
-        if miss is not None:
+        rows = self._epoch_rows(req)
+        if rows is not None:
             old = jnp.full(req.path_hash.shape, self.fallback.old_mode,
                            jnp.int32)
             f2, s2, l2 = self._meta_call(bb.OP_STAT, req, mode=old,
-                                         valid=jnp.asarray(miss))
+                                         valid=jnp.asarray(rows))
+            loc = jnp.where(f2 & ~found, l2, loc)
+            size = jnp.where(f2, jnp.maximum(size, s2), size)
             found = jnp.logical_or(found, f2)
-            size = jnp.where(f2, s2, size)
-            loc = jnp.where(f2, l2, loc)
         return found, size, loc
 
     def remove(self, req: BBRequest) -> jax.Array:
@@ -1091,14 +1106,11 @@ class BBClient:
             ph, sh = np.asarray(req.path_hash), self._scope_hashes(req)
             for i, j in zip(*np.nonzero(v)):
                 self._files.get(int(sh[i, j]), {}).pop(int(ph[i, j]), None)
-        fb = self.fallback
-        if fb is not None and req.scope_hash is not None:
-            with obs.span("client.sync.fallback", cat="client"):
-                in_scope = (np.asarray(self._valid(req)) &
-                            (self._scope_hashes(req) == fb.scope_hash))
-            if in_scope.any():
-                old = jnp.full(req.path_hash.shape, fb.old_mode, jnp.int32)
-                f2, _, _ = self._meta_call(bb.OP_REMOVE, req, mode=old,
-                                           valid=jnp.asarray(in_scope))
-                found = jnp.logical_or(found, f2)
+        rows = self._epoch_rows(req)
+        if rows is not None:
+            old = jnp.full(req.path_hash.shape, self.fallback.old_mode,
+                           jnp.int32)
+            f2, _, _ = self._meta_call(bb.OP_REMOVE, req, mode=old,
+                                       valid=jnp.asarray(rows))
+            found = jnp.logical_or(found, f2)
         return found

@@ -22,7 +22,7 @@ heterogeneous ``LayoutPolicy`` reaches the routing triplet under shard_map.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Tuple
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -118,8 +118,7 @@ def _check_specs(config: bb.ExchangeConfig, local_n: int) -> None:
 
 @obs.trace_span("mesh.build_ops", cat="build")
 def build_mesh_ops(mesh: Mesh, policy,
-                   config: bb.ExchangeConfig = bb.DENSE,
-                   donate: bool = False) -> Tuple:
+                   config: bb.ExchangeConfig = bb.DENSE) -> bb.EngineOps:
     """Returns jitted (write, read, meta, read_loc) ops bound to a mesh.
 
     Each op takes the per-request ``mode`` array right after the state
@@ -134,12 +133,11 @@ def build_mesh_ops(mesh: Mesh, policy,
     — run through the same ``mesh_exchange``/``build_mesh_shift``
     collectives.
 
-    ``donate=True`` marks the state argument of the mutating ops (write,
-    meta) as donated, letting XLA reuse the old table buffers in place
-    for the updated state.  The donated input is DELETED after the call:
-    only enable it for callers that rebind their state reference
-    (``BBClient(donate=True)`` public paths do; raw replay loops that
-    reuse a saved state must not).
+    The four keep the state they are given; their ``owned`` twins
+    (``bb.EngineOps``) donate it to write and meta, so each device's
+    table shard is updated in place.  ``BBClient``'s mutating calls run
+    the twins and rebind their state; replay loops that reuse a saved
+    state run the four.
     """
     policy = as_policy(policy)
     n_dev = mesh.shape[NODE_AXIS]
@@ -177,34 +175,32 @@ def build_mesh_ops(mesh: Mesh, policy,
     state_specs = jax.tree_util.tree_map(
         lambda _: PS(NODE_AXIS), bb.init_state(1, 1, 1, 1))
 
-    dargs = (0,) if donate else ()
-    write = jax.jit(jax.shard_map(
+    write = jax.shard_map(
         _write, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec),
-        out_specs=state_specs, check_vma=False), donate_argnums=dargs)
-    read = jax.jit(jax.shard_map(
+        out_specs=state_specs, check_vma=False)
+    read = jax.shard_map(
         _read, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec),
-        out_specs=(req_spec, req_spec), check_vma=False))
-    meta = jax.jit(jax.shard_map(
+        out_specs=(req_spec, req_spec), check_vma=False)
+    meta = jax.shard_map(
         _meta, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec, req_spec),
         out_specs=(state_specs, req_spec, req_spec, req_spec),
-        check_vma=False), donate_argnums=dargs)
-    read_loc = jax.jit(jax.shard_map(
+        check_vma=False)
+    read_loc = jax.shard_map(
         _read_loc, mesh=mesh,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec),
-        out_specs=(req_spec, req_spec), check_vma=False))
-    return write, read, meta, read_loc
+        out_specs=(req_spec, req_spec), check_vma=False)
+    return bb.jit_engine_ops(write, read, meta, read_loc)
 
 
 @obs.trace_span("mesh.build_migrate", cat="build")
 def build_mesh_migrate(mesh: Mesh, policy,
-                       config: bb.ExchangeConfig = bb.COMPACTED,
-                       donate: bool = False):
+                       config: bb.ExchangeConfig = bb.COMPACTED):
     """Jitted ``migrate_rows`` bound to a mesh + policy (live relayout).
 
     Kept separate from ``build_mesh_ops`` so existing tuple callers are
@@ -213,7 +209,8 @@ def build_mesh_migrate(mesh: Mesh, policy,
     array sharded over the node axis, and runs the same old-fetch →
     probe → copy → meta-move → tombstone sequence as the stacked
     backend, with the carry-round predicate psum-reduced so every device
-    takes the same cond branch.
+    takes the same cond branch.  The state is donated: the one caller,
+    ``BBClient.migrate_rows``, rebinds its state to the result.
     """
     policy = as_policy(policy)
     n_dev = mesh.shape[NODE_AXIS]
@@ -236,7 +233,7 @@ def build_mesh_migrate(mesh: Mesh, policy,
         in_specs=(state_specs, req_spec, req_spec, req_spec, req_spec,
                   req_spec),
         out_specs=(state_specs, req_spec, req_spec), check_vma=False),
-        donate_argnums=(0,) if donate else ())
+        donate_argnums=0)
 
 
 @obs.trace_span("mesh.build_probe", cat="build")

@@ -359,6 +359,26 @@ def test_relayout_into_hybrid_is_also_lossless():
     assert _digest(*plain) == _digest(*migrated)
 
 
+def test_stat_mid_relayout_reports_the_larger_epoch_size():
+    """A file written again during its scope's relayout, before its
+    installment, has an entry in each epoch; stat reports the larger
+    size, as it would have without the relayout."""
+    q = 2
+    client = BBClient(_policy(scope_mode=LayoutMode.DIST_HASH), cap=64,
+                      words=W, mcap=64, telemetry=True)
+    paths = [[f"{SCOPE}/r{i}/f"] * q for i in range(N)]
+    pay = np.ones((N, q, W), np.int32)
+    client.write(client.encode(paths, chunk_id=np.tile([0, 2], (N, 1)),
+                               payload=pay))
+    LiveMigrator(client, SCOPE, LayoutMode.CENTRAL_META, step_chunks=4)
+    req = client.encode(paths, chunk_id=np.ones((N, q), np.int32),
+                        payload=pay)
+    client.write(req)                      # chunk 1, new epoch only
+    found, size, _ = client.stat(req)
+    assert bool(np.asarray(found).all())
+    assert (np.asarray(size) == 3).all()
+
+
 def test_migration_moves_the_bytes_not_just_the_policy():
     client = BBClient(_policy(), cap=256, words=W, mcap=256, telemetry=True)
     paths = [[f"{SCOPE}/n{i}" for _ in range(Q)] for i in range(N)]
@@ -704,7 +724,9 @@ MESH_MIGRATE_SCRIPT = textwrap.dedent("""
         mig = LiveMigrator(c, "/bb/hot", LayoutMode.DIST_HASH,
                            step_chunks=4)
         while not mig.done:
+            before = c.state
             mig.step()                       # partial watermark each loop
+            assert before.data.is_deleted(), name    # donated, rebound
             out, found = c.read(rreq)
             assert bool(np.asarray(found).all()), (name, mig.watermark)
             outs += [out, found, *c.stat(rreq)]

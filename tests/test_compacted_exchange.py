@@ -49,14 +49,16 @@ def _client_trace(mode, exchange):
     cid = jnp.asarray(rng.randint(0, 4, (N, Q)), jnp.int32)
     payload = jnp.asarray(rng.randint(0, 9999, (N, Q, W)), jnp.int32)
     client.write(BBRequest(path_hash=ph, chunk_id=cid, payload=payload))
+    # the digest is taken before the stat, which donates this state
     state = client.state
+    state_digest = _digest(state.data, state.data_keys, state.data_count,
+                           state.meta_key, state.meta_size, state.meta_loc,
+                           state.meta_count, state.dropped)
     perm = rng.permutation(N)
     rpay, rfound = client.read(BBRequest(path_hash=ph[perm],
                                          chunk_id=cid[perm]))
     fnd, size, loc = client.stat(BBRequest(path_hash=ph))
-    return {"state": _digest(state.data, state.data_keys, state.data_count,
-                             state.meta_key, state.meta_size, state.meta_loc,
-                             state.meta_count, state.dropped),
+    return {"state": state_digest,
             "read": _digest(rpay, rfound),
             "meta": _digest(fnd, size, loc)}
 

@@ -157,16 +157,64 @@ def test_under_budget_write_keeps_serial_rounds():
 
 
 # ---------------------------------------------------------------------------
-# donation: donate=True may reuse buffers, never change results
+# donation: the public calls hand their state over, never changing results
 # ---------------------------------------------------------------------------
-def test_donation_parity_stacked():
-    streams = {}
-    for donate in (False, True):
-        client = BBClient(_hash_policy(), cap=8 * Q, words=W, mcap=8 * Q,
-                          exchange="compacted", budget=Q, pipeline=True,
-                          donate=donate)
-        streams[donate] = _drive(client, [0, 1, 2, 0, 1, 2], seed=5)
-    assert _digest(*streams[False]) == _digest(*streams[True])
+def _replay(client, ops, seed):
+    """``_drive``'s stream through the state-explicit entries, which keep
+    every state they are given; returns the observables and the states."""
+    rng = np.random.RandomState(seed)
+    outs, reqs, states = [], [], [client.state]
+    for kind in ops:
+        if kind == 0 or not reqs:
+            req = BBRequest(
+                path_hash=jnp.asarray(
+                    rng.randint(1, 1 << 12, (client.n_nodes, Q)),
+                    jnp.int32),
+                chunk_id=jnp.asarray(
+                    rng.randint(0, 4, (client.n_nodes, Q)), jnp.int32),
+                payload=jnp.asarray(
+                    rng.randint(0, 9999, (client.n_nodes, Q, W)),
+                    jnp.int32),
+                valid=jnp.asarray(rng.rand(client.n_nodes, Q) < 0.85))
+            states.append(client._write(
+                states[-1], client._modes(req), req.path_hash,
+                req.chunk_id, req.payload, req.valid))
+            reqs.append(req)
+            continue
+        req = reqs[rng.randint(len(reqs))]
+        mode, valid = client._modes(req), client._valid(req)
+        if kind == 1:
+            outs += list(client._read(states[-1], mode, req.path_hash,
+                                      req.chunk_id, valid))
+        else:
+            shape = req.path_hash.shape
+            state, *reply = client._meta(
+                states[-1], mode, jnp.full(shape, bb.OP_STAT, jnp.int32),
+                req.path_hash, jnp.zeros(shape, jnp.int32),
+                jnp.full(shape, -1, jnp.int32), valid)
+            states.append(state)
+            outs += reply
+    outs += list(states[-1].tree_flatten()[0])
+    return outs, states
+
+
+@pytest.mark.parametrize("exchange", ["dense", "compacted"])
+def test_donation_parity_stacked(exchange):
+    """The public calls donate the state they rebind; a replay of the
+    same stream through the state-explicit entries donates nothing.
+    Both land on one digest, and every state of the replay survives."""
+    ops = [0, 1, 2, 0, 1, 2]
+    kw = dict(cap=8 * Q, words=W, mcap=8 * Q, exchange=exchange,
+              pipeline=True)
+    if exchange == "compacted":
+        kw["budget"] = Q
+    public = BBClient(_hash_policy(), **kw)
+    first = public.state
+    owned = _drive(public, ops, seed=5)
+    assert first.data.is_deleted()
+    replayed, states = _replay(BBClient(_hash_policy(), **kw), ops, seed=5)
+    assert not any(s.data.is_deleted() for s in states)
+    assert _digest(*owned) == _digest(*replayed)
 
 
 # ---------------------------------------------------------------------------
@@ -250,9 +298,13 @@ MESH_PIPELINE_SCRIPT = textwrap.dedent("""
             c = BBClient(policy, make_node_mesh(N), cap=128, words=w,
                          mcap=128, exchange="compacted", budget=budget,
                          pipeline=pipe)
-            c.write(req)
+            before = c.state
+            c.write(req)               # public calls donate sharded state
+            assert before.data.is_deleted(), budget
             out, fnd = c.read(req)
+            before = c.state
             st = c.stat(req)
+            assert before.data.is_deleted(), budget
             outs.append((c.state, out, fnd, st))
         (sa, oa, fa, ta), (sb, ob_, fb, tb) = outs
         for a, b in zip(sa.tree_flatten()[0], sb.tree_flatten()[0]):
@@ -270,7 +322,8 @@ MESH_PIPELINE_SCRIPT = textwrap.dedent("""
 def test_mesh_pipeline_parity():
     """Fused write round-trips and hoisted carry plans on a real
     4-device shard_map mesh: ``pipeline`` on/off leaves every table and
-    every reply bit-identical, at B = q (fused) and B = 2 (carry)."""
+    every reply bit-identical, at B = q (fused) and B = 2 (carry).  The
+    public write and stat donate the sharded state they rebind."""
     r = subprocess.run([sys.executable, "-c", MESH_PIPELINE_SCRIPT],
                        capture_output=True, text=True, timeout=600,
                        cwd=".")
