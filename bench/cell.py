@@ -5,6 +5,11 @@ mix (``bench/traffic/<traffic>.json``).  Set-up runs the decision layer on
 the mix's Table-I job, builds a ``BBClient`` on the policy it returns (every
 option but the sizes at its default), makes the payload pool on the device
 from the seed, and runs one whole round, which builds every program.  The
+configuration's ``backend`` picks the data plane: ``"stacked"`` holds every
+node table on one chip; ``"mesh"`` spreads the nodes over a 1-D mesh of the
+cell's ``chips`` (``mesh_engine.make_node_mesh``), and the tables, the
+payload pool and every answer are sharded over its node axis, so each
+node's ranks write from their own chip.  The
 window then drives the same round plan for ``seconds``, closed loop: each
 call is ``encode`` plus one client op, ended by ``block_until_ready``.  A
 drain ends every round of a data mix: a ``remove`` of the round's files and
@@ -156,7 +161,11 @@ class Cell:
         self.mode = decision.mode
         self.records_loc = LayoutMode.HYBRID in self.policy.modes_present()
         t1 = time.perf_counter()
-        self.client = BBClient(self.policy, self.config["backend"],
+        backend = self.config["backend"]
+        if backend == "mesh":
+            from repro.core.mesh_engine import make_node_mesh
+            backend = make_node_mesh(int(self.spec["chips"]))
+        self.client = BBClient(self.policy, backend,
                                cap=self.cap, words=self.words,
                                mcap=self.mcap, **self.client_options)
         jax.block_until_ready(self.client.state)
@@ -165,11 +174,14 @@ class Cell:
         self.host["tables_s"] = t2 - t1
         self.pool = []
         if self.mix.pool:
+            # the data table's sharding: one chip for the stacked tables,
+            # the node axis of the mesh, so that a rank's buffer lives on
+            # its own node
             self.pool = _make_pool(
                 int(np.random.default_rng(self.seed).integers(0, 2**31)),
                 slots=self.mix.pool,
                 shape=(self.nodes, self.mix.q, self.words),
-                sharding=jax.sharding.SingleDeviceSharding(jax.devices()[0]))
+                sharding=self.client.state.data.sharding)
             jax.block_until_ready(self.pool)
         self.host["pool_s"] = time.perf_counter() - t2
 
@@ -189,6 +201,8 @@ class Cell:
             return (count, digest, jnp.zeros_like(data),
                     jnp.full_like(keys, -1), jnp.zeros_like(count))
 
+        # the new stamps keep the pool's sharding: XLA propagates the
+        # donated buffer's
         self._stamp = jax.jit(stamp, donate_argnums=0)
         cs, ds, ks = (st.data_count.sharding, st.data.sharding,
                       st.data_keys.sharding)
@@ -280,6 +294,17 @@ class Cell:
             times.setdefault("drain", []).append(dt)
         self.records.append(Record("drain", None, self.rnd,
                                    (found, count, digest), call_s=dt))
+
+    def exchange_plans(self) -> List[str]:
+        """The exchanges of the mesh programs the client has built: the
+        kind, and the executor that each measured plan picked for data and
+        for metadata (``uniform``: no measured plan).  Empty for the
+        stacked backend."""
+        def executor(spec):
+            return getattr(spec, "executor", "uniform")
+        cfgs = {**self.client._mesh_ops, **self.client._mesh_probe}
+        return sorted({f"{c.kind} data {executor(c.data_spec)} meta "
+                       f"{executor(c.meta_spec)}" for c in cfgs})
 
     def run_round(self) -> None:
         start = self.rnd

@@ -179,6 +179,9 @@ def main(argv=None, client_options=None, patch=None) -> int:
     log(f"warm-up: 1 round; executables built {clock.builds} "
         f"(persistent-cache hits {clock.cache_hits}), compile "
         f"{clock.seconds:.3f} s")
+    if cell.exchange_plans():
+        log(f"mesh exchange plans built: "
+            f"{'; '.join(cell.exchange_plans())}")
     log(f"set-up: import and chip init {init_s:.3f} s, decision "
         f"{cell.host['decide_s']:.3f} s, tables and helpers "
         f"{cell.host['tables_s']:.3f} s, payload pool "

@@ -12,6 +12,9 @@ Faults (each one the check must read as not correct):
 - ``half``: the second half of every request batch is left out;
 - ``altered``: a word of every write payload, of every read answer and of
   every stat size is changed where the engine produces it;
+- ``exchange``: the exchange between chips is left out (a mesh cell):
+  ``all_to_all`` and the ``ppermute`` shift rounds return each chip's own
+  buffer;
 - ``control``: the control of ``bench/control.py``, the program's own
   lossy fixed-budget exchange.
 """
@@ -66,6 +69,21 @@ def wrap_ops(cell, fault: str) -> None:
     client._ops = ops
 
 
+def cut_exchange():
+    """From here on, the mesh programs built leave out the exchange between
+    chips: each chip keeps the buffer it would have sent.  Returns the
+    undo."""
+    from repro.core import mesh_engine
+    saved = mesh_engine.mesh_exchange, mesh_engine.build_mesh_shift
+
+    def undo():
+        mesh_engine.mesh_exchange, mesh_engine.build_mesh_shift = saved
+
+    mesh_engine.mesh_exchange = lambda x: x
+    mesh_engine.build_mesh_shift = lambda n_dev: lambda x, k: x
+    return undo
+
+
 def run_fault(cell: str, fault: str) -> str:
     """One rehearsal of ``cell`` with ``fault``; its result line."""
     import contextlib
@@ -77,6 +95,15 @@ def run_fault(cell: str, fault: str) -> str:
         if fault == "control":
             from control import control_options
             run.main(argv, client_options=control_options)
+        elif fault == "exchange":
+            # after set-up, which starts JAX on the rehearsal's devices;
+            # the client builds its programs in the warm-up round
+            undo = []
+            try:
+                run.main(argv, patch=lambda c: undo.append(cut_exchange()))
+            finally:
+                for u in undo:
+                    u()
         else:
             run.main(argv, patch=lambda c: wrap_ops(c, fault))
     return out.getvalue().strip().splitlines()[-1]

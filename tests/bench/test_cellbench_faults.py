@@ -1,5 +1,8 @@
 """Each cell, rehearsed with a fault planted under its timed path, and with
-the control (the program's own lossy exchange), reads as not correct."""
+the control (the program's own lossy exchange), reads as not correct.  The
+four-chip cell runs on four CPU devices, which ``run.main`` forces for a
+rehearsal, and has the fault only a mesh can have: no exchange between
+chips."""
 import json
 import os
 import subprocess
@@ -14,6 +17,8 @@ FAULTS = {
     "bb8_1chip.ior_d": ["control", "unchanged", "half", "altered"],
     "bb8_1chip.ior_a": ["control", "unchanged", "half", "altered"],
     "bb8_1chip.mdtest_a": ["control", "unchanged", "half", "altered"],
+    "bb4_4chip.ior_d": ["control", "unchanged", "half", "altered",
+                        "exchange"],
 }
 
 
