@@ -165,13 +165,10 @@ def bench_node(n: int, q: int, w: int, iters: int) -> Dict:
     dest = route_data(mode_np, n, np.asarray(ph_hash),
                       np.zeros((n, q), np.int32), ranks, xp=np)
     spec = plan_mesh_ragged_spec(dest, np.ones((n, q), bool), n,
-                                 row_bytes=row_bytes,
-                                 node_ids=np.arange(n))
-    spec = dataclasses.replace(spec, executor="ppermute")
+                                 row_bytes=row_bytes, executor="ppermute")
 
     def ppermute_rounds():
-        return [n * wk * row_bytes for wk in spec.round_widths[1:]
-                if wk > 0]
+        return [n * wk * row_bytes for wk in spec.segments]
 
     cells = [overlap_cell(
         "ppermute",

@@ -390,11 +390,8 @@ def mixed_and_metadata_phase(sz: Sizes, rng, phases: Phases) -> None:
 
 
 def _forced(cfg, executor: str):
-    from repro.core.exchange_plan import MeshRaggedSpec
-
     def force(spec):
-        return None if spec is None else MeshRaggedSpec(
-            spec.budgets, spec.round_widths, executor)
+        return None if spec is None else spec.with_executor(executor)
 
     return dataclasses.replace(cfg, data_spec=force(cfg.data_spec),
                                meta_spec=force(cfg.meta_spec))

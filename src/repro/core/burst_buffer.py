@@ -18,8 +18,8 @@ and hands both to ``run_exchange``, which plans the routing permutation,
 ships the buffer through the executor the planner picked, applies it, and
 routes the replies back — including the one shared copy of the lossless
 carry round.  The executors (dense broadcast / uniform-budget all_to_all /
-packed ragged / ppermute-segmented mesh ragged) are interchangeable
-transports; see exchange_plan.py for the full matrix and docs/exchange.md
+packed ragged / the mesh's two-level split, padded or chip-ring) are
+interchangeable transports; see exchange_plan.py for the full matrix and docs/exchange.md
 for the measured trade-offs.  A single exchange round therefore serves a
 *mixed-mode* batch: the Mode-1/4 local fast path, hashed routing, and the
 hybrid two-phase read are mask-combined paths over the same plan/execute
@@ -67,7 +67,8 @@ from repro.kernels.chunk_pack.ops import gather_rows_batched
 # planner's vocabulary as ``burst_buffer.*``)
 from repro.core.exchange_plan import (  # noqa: F401  (re-exports)
     COMPACTED, DENSE, DenseExecutor, ExchangeConfig, ExchangePlan,
-    LOCAL_WRITE_MODES, MeshRaggedSpec, PermuteExecutor, RaggedExecutor,
+    LOCAL_WRITE_MODES, MeshRaggedSpec, MeshSplitExecutor, PermuteExecutor,
+    RaggedExecutor,
     RaggedSpec, UniformExecutor, _auto_budget, _carry_budget, _carry_taken,
     _compact_plan, _compact_plan_ragged, bucketize, build_executor,
     collect_replies, compact_bucketize, compact_collect,
@@ -570,9 +571,18 @@ def forward_read(state: BBState, layout, path_hash: jax.Array,
         # searched (costed as a redirect penalty in the simulator).
         miss = valid & ~found & ((mode == LayoutMode.NODE_LOCAL) |
                                  (mode == LayoutMode.HYBRID))
-        bpay, bfound = _broadcast_lookup(state, keys, miss, exchange, N)
-        payload = jnp.where(bfound[..., None], bpay, payload)
-        found = found | bfound
+
+        def search(got):
+            bpay, bfound = _broadcast_lookup(state, keys, miss, exchange, N)
+            return (jnp.where(bfound[..., None], bpay, got[0]),
+                    got[1] | bfound)
+
+        # a read that found every chunk where it looked skips the
+        # broadcast; ``global_sum`` reduces over every node, so all
+        # devices take one branch and its collectives stay aligned
+        payload, found = jax.lax.cond(
+            global_sum(miss.astype(jnp.int32)) > 0, search,
+            lambda got: got, (payload, found))
     return payload, found
 
 

@@ -10,9 +10,10 @@ Backends:
 
 * ``"stacked"`` — single-device execution; the cross-node exchange is a
   transpose of the (src, dst) axes.  Tests, probes, CPU-only quickstarts.
-* a ``jax.sharding.Mesh`` — the node axis is sharded 1-per-device under
-  ``shard_map`` and the exchange is ``lax.all_to_all`` (mesh_engine.py).
-  This is the production data plane.
+* a ``jax.sharding.Mesh`` — the node axis is sharded over the devices
+  under ``shard_map`` (any whole number of node rows per device) and the
+  exchange is ``lax.all_to_all`` (mesh_engine.py).  This is the
+  production data plane.
 
 Both backends run the *identical* engine code (burst_buffer.py), so results
 are element-for-element equal — asserted in tests/test_policy.py.
@@ -25,11 +26,12 @@ Orthogonally, ``exchange=`` picks the exchange data plane *per call*:
   exchange volume.  Budgets are *ragged* by default on BOTH backends:
   sized per destination from the measured ``chunk_router`` histograms of
   each call (lossless by construction).  The stacked backend packs them
-  into one (L, Σbᵢ) buffer; the mesh backend — whose ``all_to_all`` needs
-  uniform splits — plans a ``MeshRaggedSpec`` instead: pad to the global
-  max budget for the ordinary ``all_to_all``, or run the ``ppermute``
-  segmented rounds when the measured histogram is skewed (the executor
-  pick keys on the measured fabric model — ``exchange_select``).  With an
+  into one (L, Σbᵢ) buffer; the mesh backend plans a two-level
+  ``MeshRaggedSpec`` instead: rows for a node on the same chip stay on
+  the chip, rows for other chips are compacted per chip pair and cross
+  in one padded ``all_to_all`` over the chips, or in one ``ppermute`` per
+  chip offset with traffic (the pick keys on the measured fabric model —
+  ``exchange_select``).  With an
   explicit ``budget=``/``ragged=False`` budgets are uniform and
   jit-static, and overflow is carried into a rarely-taken second exchange
   round (``lossless=True``, default) instead of dropped.  Hybrid reads —
@@ -250,10 +252,10 @@ class BBClient:
           ragged: size compacted budgets per destination from each call's
             measured histograms (jit ops then specialize per traffic
             shape).  The stacked backend packs them (``RaggedSpec``); a
-            mesh backend plans a ``MeshRaggedSpec`` — global-max padded
-            ``all_to_all``, or the ``ppermute`` segmented exchange when
-            the measured fabric model says the histogram is skewed enough
-            to pay for the extra rounds.
+            mesh backend plans a two-level ``MeshRaggedSpec`` — same-chip
+            rows stay on the chip, the rest cross in a padded chip
+            ``all_to_all`` or on the chip ring, as the measured fabric
+            model prices them.
           two_phase: run hybrid reads as metadata probe → ragged data
             round (both backends); ``False`` keeps the single-call
             uniform-budget plan.  Only meaningful with ``ragged=True``.
@@ -314,14 +316,15 @@ class BBClient:
         self._mesh_probe: Dict[bb.ExchangeConfig, object] = {}
         self.ragged = bool(ragged)
         self.two_phase = bool(two_phase) and self.ragged
-        # ppermute segmented plans rotate the device ring, so they need
-        # nodes 1:1 with mesh devices; otherwise only the padded plan runs
-        self._ppermute_ok = (self._is_mesh and
-                             dict(backend.shape).get("node") == self.n_nodes)
-        # telemetry-seeded ragged presizing: running per-destination
-        # high-water budgets per (role, q) — a steady workload converges
-        # to ONE spec (one jit specialization) instead of re-planning
-        self._spec_floor: Dict[Tuple[str, int], np.ndarray] = {}
+        # chips of the mesh: each holds n_nodes / n_chips node rows
+        self._n_chips = (dict(backend.shape)["node"] if self._is_mesh
+                         else 1)
+        # ragged presizing: running high-water sizes per (op, role, q) —
+        # per-destination budgets on the stacked backend, the mesh spec's
+        # ``sizes`` on a mesh — so a steady workload converges to ONE spec
+        # (one jit specialization) per op instead of re-planning, and one
+        # op's widths never widen another's
+        self._spec_floor: Dict[Tuple[str, str, int], object] = {}
         # measured carry-width floor per q (see _carry_hint): same
         # converge-to-one-specialization discipline as _spec_floor
         self._hint_floor: Dict[int, int] = {}
@@ -584,36 +587,46 @@ class BBClient:
     def _client_ranks(self) -> jax.Array:
         return jnp.arange(self.n_nodes, dtype=jnp.int32)[:, None]
 
-    def _plan_spec(self, role: str, dest, valid, row_bytes: int):
+    def _plan_spec(self, op: str, role: str, dest, valid, row_bytes: int):
         """Measure one call's ragged spec, with convergent presizing.
 
-        The measured per-destination budgets are maxed into a running
-        per-(role, q) floor that seeds every later plan — so a steady
+        The measured sizes are maxed into a running per-(op, role, q)
+        floor that seeds every later plan of that op — so a steady
         workload's specs grow monotonically to a fixed point (ONE jit
-        specialization) instead of re-planning per hashed batch.  When
-        telemetry rides the client, its live extent histogram picks the
-        quantization step (``suggest_align``), seeding the convergence
-        coarser for large steady workloads.  Mesh backends plan a
-        ``MeshRaggedSpec`` (padded vs ppermute picked from the measured
-        fabric model via ``row_bytes`` per exchanged column).
+        specialization) instead of re-planning per hashed batch, and a
+        write's widths never widen a read's.  When telemetry rides the
+        client, its live extent histogram picks the quantization step
+        (``suggest_align``).  Mesh backends plan a two-level
+        ``MeshRaggedSpec`` (padded chip ``all_to_all`` vs chip ring,
+        picked from the measured fabric model via ``row_bytes`` per
+        exchanged row), keyed only by the sizes its executor runs, and
+        count its rows by path (``exchange_rows_total``) and the bytes
+        its fabric block ships (``exchange_fabric_bytes_total``) from
+        the planner's host histogram.
         """
-        key = (role, dest.shape[1])
+        key = (op, role, dest.shape[1])
         floor = self._spec_floor.get(key)
         align = self._suggest_align(dest.shape[1])
         if self._is_mesh:
             spec = bb.plan_mesh_ragged_spec(
-                dest, valid, self.n_nodes, align=align,
-                row_bytes=row_bytes, allow_ppermute=self._ppermute_ok,
-                floor=floor)
+                dest, valid, self.n_nodes, n_chips=self._n_chips,
+                align=align, row_bytes=row_bytes, floor=floor)
+            new_floor = spec.sizes
+            grew = new_floor != floor
+            if self.obs is not None:
+                m = self.obs.metrics
+                m.inc("exchange_rows_total", spec.local_rows, op=op,
+                      path="local")
+                m.inc("exchange_rows_total", spec.fabric_rows, op=op,
+                      path="fabric")
+                m.inc("exchange_fabric_bytes_total",
+                      spec.n_chips * spec.exchanged_cols * row_bytes, op=op)
         else:
             spec = bb.plan_ragged_spec(dest, valid, self.n_nodes,
                                        align=align, floor=floor)
-        budgets = np.asarray(spec.budgets, np.int64)
-        if floor is None:
-            grew, new_floor = True, budgets
-        else:
-            grew = bool((budgets > floor).any())
-            new_floor = np.maximum(floor, budgets) if grew else floor
+            # the planner raised the budgets to the floor already
+            new_floor = np.asarray(spec.budgets, np.int64)
+            grew = floor is None or bool((new_floor != floor).any())
         if grew and self.obs is not None:
             # a grown floor means a new spec → a fresh jit specialization
             self.obs.metrics.inc("ragged_respecializations_total", role=role)
@@ -658,8 +671,10 @@ class BBClient:
         the two-phase hybrid read's probed data-location array: with it,
         read destinations ARE computable here and the data round gets a
         measured plan; without it a hybrid read keeps the uniform
-        lossless plan for the whole call.  Runs in a ``client.plan``
-        span, routing in ``client.route``."""
+        lossless plan for the whole call.  ``op`` is ``write``, ``read``,
+        ``meta`` or ``probe`` (the two-phase read's metadata round,
+        planned like ``meta`` under its own floor).  Runs in a
+        ``client.plan`` span, routing in ``client.route``."""
         with obs.span("client.plan", cat="client"):
             q = ph.shape[1]
             kind = self._select_kind(q)
@@ -671,9 +686,14 @@ class BBClient:
             if not self.ragged or q == 0:
                 return cfg
             N, client = self.n_nodes, self._client_ranks()
-            if op in ("write", "read") and cfg.budget is None:
+            present = self.policy.modes_present()
+            # a write whose every mode stores locally exchanges no data
+            # (``forward_write``'s local fast path): nothing to plan
+            local_write = op == "write" and present <= bb.LOCAL_WRITE_MODES
+            if op in ("write", "read") and cfg.budget is None and \
+                    not local_write:
                 if op == "read" and data_loc is None and \
-                        LayoutMode.HYBRID in self.policy.modes_present():
+                        LayoutMode.HYBRID in present:
                     # hybrid read destinations come from the metadata phase
                     # (table state), which is invisible here — the two-phase
                     # path probes first and calls back in with data_loc
@@ -683,9 +703,9 @@ class BBClient:
                                       data_loc=data_loc, xp=jnp)
                 cfg = dataclasses.replace(
                     cfg, data_spec=self._plan_spec(
-                        "data", dest, valid, 4 * (self.words + 3)))
-            if op in ("write", "meta") and cfg.meta_budget is None and \
-                    cfg.budget is None:
+                        op, "data", dest, valid, 4 * (self.words + 3)))
+            if op in ("write", "meta", "probe") and \
+                    cfg.meta_budget is None and cfg.budget is None:
                 # an explicit ``budget`` historically also caps the metadata
                 # exchange (see ``meta_budget``) — honour it rather than
                 # silently upgrading metadata to ragged sizing
@@ -693,7 +713,7 @@ class BBClient:
                     owner = route_meta(mode, N, self.policy.n_md_servers, ph,
                                        client, xp=jnp)
                 cfg = dataclasses.replace(
-                    cfg, meta_spec=self._plan_spec("meta", owner, valid,
+                    cfg, meta_spec=self._plan_spec(op, "meta", owner, valid,
                                                    4 * 8))
             if cfg.pipeline and cfg.lossless and cfg.budget is not None:
                 # explicit uniform budgets skip ragged sizing, but the carry
@@ -726,7 +746,7 @@ class BBClient:
         b_d = bb.data_budget(policy, q, cfg)
         b_m = bb.meta_budget(policy, q, cfg)
         data = op in ("write", "read") and b_d < q
-        meta = op in ("write", "meta") and b_m < q
+        meta = op in ("write", "meta", "probe") and b_m < q
         if data and op == "read" and data_loc is None and \
                 LayoutMode.HYBRID in policy.modes_present():
             return None            # destinations live in table state
@@ -853,7 +873,7 @@ class BBClient:
                 # every data destination resolves without table state
                 data_loc = ranks
             else:
-                cfg_m = self._call_config("meta", mode, ph, None,
+                cfg_m = self._call_config("probe", mode, ph, None,
                                           probe_valid)
                 with obs.span("client.dispatch", cat="client"):
                     fm, loc = h.fence(self._probe_op(cfg_m)(

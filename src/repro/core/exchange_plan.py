@@ -24,18 +24,17 @@ exchange routing now lives:
                       lossless via the cond-gated carry round
   ``RaggedExecutor``  packed (L, Σbᵢ) histogram-sized segments
                       (:class:`RaggedSpec`), stacked backend
-  ``PermuteExecutor`` ``ppermute``-based segmented exchange
-                      (:class:`MeshRaggedSpec`): N−1 shift rounds with
-                      *measured per-round widths* — the mesh backend's
-                      skew-proof ragged plan (round 0 is the free local
-                      pass)
+  ``MeshSplitExecutor`` two-level mesh exchange (:class:`MeshRaggedSpec`):
+                      same-chip rows move on the chip, rows for other
+                      chips are compacted per chip pair and cross in one
+                      padded ``all_to_all`` over the chips
+  ``PermuteExecutor`` the same split, its fabric block on the chip ring:
+                      one ``ppermute`` per chip offset with traffic, at
+                      *measured widths*
   ==================  =====================================================
 
-  The mesh "padded" ragged plan (pad every segment to the psum'd global
-  max budget and ride the ordinary ``all_to_all``) is deliberately NOT a
-  fifth executor: it *is* ``UniformExecutor`` with the measured
-  ``bmax`` — lossless by construction, so the carry round is statically
-  elided.
+  The mesh executors' widths are measured per call, so they are lossless
+  by construction and the carry round is statically elided.
 
 * :func:`run_exchange` — the shared round runner: plan → send →
   receiver-apply → reply collect, plus the ONE copy of the lossless
@@ -44,15 +43,17 @@ exchange routing now lives:
 
 Backend reach: executors see two collective hooks — ``exchange`` (the
 src/dst transpose: ``stacked_exchange`` or ``mesh_engine.mesh_exchange``)
-and ``shift`` (a k-step rotation over the node axis: :func:`stacked_shift`
+and ``shift`` (a k-step rotation over the device ring: :func:`stacked_shift`
 or a ``lax.ppermute`` closure).  The same executor code therefore runs
-single-device and under ``shard_map``; parity tests exploit that by
-digesting the ppermute plan on the stacked backend first.
+single-device and under ``shard_map``; on the stacked backend the mesh
+executors treat the node rows as ``n_chips`` groups and the hooks move
+whole groups, so parity tests digest a many-chip plan on one device
+first.
 """
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, replace
+from dataclasses import dataclass, field, replace
 from functools import cached_property
 from typing import Callable, Dict, Optional, Tuple, Union
 
@@ -195,85 +196,104 @@ class RaggedSpec:
 
 @dataclass(frozen=True)
 class MeshRaggedSpec:
-    """Static mesh-ragged exchange plan: measured budgets, uniform splits.
+    """Static two-level mesh exchange plan, keyed only by what it runs.
 
-    The mesh ``all_to_all`` needs equal per-destination splits, so ragged
-    Σbᵢ packing cannot cross it directly.  Two measured plans can:
+    A mesh of ``n_chips`` devices holds ``L = N / n_chips`` node rows per
+    chip.  Every exchange splits in two:
 
-    * ``executor="padded"`` — pad every destination segment to ``bmax``,
-      the global maximum of the per-(source, destination) histograms (the
-      psum-reduced ``chunk_router`` counts), and ride the ordinary
-      ``all_to_all`` at (L, N, bmax).  Cheap when traffic is even; the
-      padding approaches uniform ``q`` when one destination is hot.
-    * ``executor="ppermute"`` — a segmented exchange of N−1 ``ppermute``
-      shift rounds; round k carries only width ``round_widths[k]`` — the
-      measured maximum any node sends to its rank+k neighbour — so a
-      skewed histogram pays for its one hot (source, destination) pair in
-      ONE round instead of padding every pair.  Round 0 (self traffic)
-      never crosses the fabric at all.
+    * the **local block** — rows whose destination node sits on the
+      sending chip — is gathered on the chip and never reaches a
+      collective;
+    * the **fabric block** — rows bound for another chip — is compacted
+      per (source chip, destination chip), so a chip pair pays for the
+      rows it carries, not for every node pair between the two chips.
 
-    ``plan_mesh_ragged_spec`` measures both and picks the executor from
-    the measured fabric cost model (``exchange_select.pick_mesh_executor``).
-    Budgets/widths are concrete Python ints (jit-static); hash/eq by
-    field tuple so jitted ops cache per traffic shape.
+    The fabric block rides one of two transports:
+
+    * ``executor="padded"`` — one ``all_to_all`` over the chips at width
+      ``widths[0]``, the widest chip pair with traffic;
+    * ``executor="ppermute"`` — the chip ring: one ``ppermute`` shift per
+      chip offset ``k`` with traffic, at width ``widths[k - 1]``, so an
+      op whose rows all go one chip along the ring ships one segment.
+
+    Every node then sees the rows sent to it, local and fabric, in
+    source-major order in a receive view of ``view`` rows: the order the
+    stacked exchange delivers, so every table digest is the stacked one.
+
+    Only ``executor``, ``n_chips``, ``view`` and ``widths`` hash and
+    compare, so only they key the jitted engine programs; they are
+    quantized (``plan_mesh_ragged_spec``) so that a workload lands on one
+    key whatever its hashed paths.  The per-node summaries of the
+    measured call — ``budgets`` (per destination node, the max over
+    sources), ``round_widths`` (per node shift ``k``, the widest
+    ``i → i + k`` run), ``local_rows`` and ``fabric_rows`` — and
+    ``sizes`` (the floored view, padded width and ring widths, which the
+    client keeps as its floor) serve the planner, the client's counters
+    and the tests.  No executor reads them.
     """
 
-    budgets: Tuple[int, ...]       # per-destination global-max budgets
-    round_widths: Tuple[int, ...]  # per-shift-k widths; [0] is local
-    executor: str = "padded"       # "padded" | "ppermute"
+    executor: str = "padded"
+    n_chips: int = 1
+    view: int = 0
+    widths: Tuple[int, ...] = ()
+    budgets: Tuple[int, ...] = field(default=(), compare=False)
+    round_widths: Tuple[int, ...] = field(default=(), compare=False)
+    local_rows: int = field(default=0, compare=False)
+    fabric_rows: int = field(default=0, compare=False)
+    sizes: Optional[Tuple] = field(default=None, compare=False)
 
     def __post_init__(self):
         if self.executor not in ("padded", "ppermute"):
             raise ValueError(f"unknown mesh ragged executor "
                              f"{self.executor!r}; pass 'padded' or "
                              "'ppermute'")
+        if self.n_chips < 1:
+            raise ValueError("a mesh spec needs at least one chip")
+        want = (self.n_chips - 1 if self.executor == "ppermute"
+                else int(self.n_chips > 1))
+        if len(self.widths) != want:
+            raise ValueError(
+                f"a {self.executor} spec over {self.n_chips} chips takes "
+                f"{want} fabric widths, got {len(self.widths)}")
         if len(self.round_widths) != len(self.budgets):
             raise ValueError("round_widths and budgets must both have one "
                              "entry per node")
 
-    @property
-    def n_nodes(self) -> int:
-        """Number of nodes (= destinations = shift rounds)."""
-        return len(self.budgets)
-
-    @cached_property
-    def bmax(self) -> int:
-        """Global max per-destination budget — the padded-path width."""
-        return max(self.budgets) if self.budgets else 0
+    def with_executor(self, executor: str) -> "MeshRaggedSpec":
+        """The same measured plan on the other transport (``sizes``
+        holds both transports' widths)."""
+        _, padded, ring = self.sizes
+        widths = (((padded,) if self.n_chips > 1 else ())
+                  if executor == "padded" else tuple(ring))
+        return replace(self, executor=executor, widths=widths)
 
     @property
-    def total(self) -> int:
-        """Σ round widths — the ppermute plan's packed column count."""
-        return sum(self.round_widths)
+    def segments(self) -> Tuple[int, ...]:
+        """Fabric send segment widths: one per destination chip (padded),
+        one per chip offset with traffic (ppermute)."""
+        if self.executor == "padded":
+            w = self.widths[0] if self.widths else 0
+            return (w,) * self.n_chips if w else ()
+        return tuple(w for w in self.widths if w > 0)
 
-    @cached_property
-    def offsets(self) -> np.ndarray:
-        """(n_nodes + 1,) exclusive prefix sum of ``round_widths``.
+    @property
+    def offsets(self) -> Tuple[int, ...]:
+        """Chip offset of each ``ppermute`` segment (ring form only)."""
+        return tuple(k for k, w in enumerate(self.widths, 1) if w > 0)
 
-        The trailing extra entry is the invalid-destination sentinel slot
-        used by the reply-index computation.
-        """
-        return np.concatenate(
-            [[0], np.cumsum(self.round_widths)]).astype(np.int32)
-
-    @cached_property
-    def col_round(self) -> np.ndarray:
-        """(total,) shift round owning each packed column."""
-        return np.repeat(np.arange(self.n_nodes, dtype=np.int32),
-                         self.round_widths)
-
-    @cached_property
-    def col_pos(self) -> np.ndarray:
-        """(total,) rank of each packed column within its round."""
-        return np.concatenate(
-            [np.arange(w, dtype=np.int32) for w in self.round_widths]
-        ).astype(np.int32) if self.total else np.zeros(0, np.int32)
+    @property
+    def operand_cols(self) -> int:
+        """Rows of one chip's fabric send buffer (the collective operand,
+        the self segment of the padded form included)."""
+        return sum(self.segments)
 
     @property
     def exchanged_cols(self) -> int:
-        """Columns actually crossing the fabric (round 0 stays local)."""
-        return sum(self.round_widths[1:])
-
+        """Rows one chip ships over the fabric per direction (the padded
+        form's self segment stays on the chip)."""
+        if self.executor == "padded" and self.segments:
+            return self.operand_cols - self.segments[0]
+        return self.operand_cols
 
 # ---------------------------------------------------------------------------
 # spec measurement (eager, client-side)
@@ -326,76 +346,103 @@ def plan_ragged_spec(dest: jax.Array, valid: jax.Array, n_nodes: int,
     return RaggedSpec(tuple(int(b) for b in budgets))
 
 
+def _mesh_width(counts: np.ndarray, cap: int, align: int) -> int:
+    """One quantized exchange width covering every bin of ``counts``.
+
+    The width is the next power of two at or above the largest bin, at
+    least ``align`` and at most ``cap`` (the most any bin can hold); 0
+    when no bin has traffic.  Where the bins with traffic carry unequal
+    loads — rows spread by a hash — the width also covers three times
+    their mean, so that the tail of another hash draw (another seed's
+    paths, the next round's files) still fits and every draw of one
+    workload plans the same width: one jit key, one compiled program.
+    Equal bins (structural traffic: every node reading its neighbour)
+    keep their exact width.
+    """
+    nz = counts[counts > 0]
+    if not nz.size:
+        return 0
+    need = int(nz.max())
+    if int(nz.min()) < need:
+        need = max(need, -(-3 * int(nz.sum()) // nz.size))
+    return int(min(cap, max(align, 1 << (need - 1).bit_length())))
+
+
 def plan_mesh_ragged_spec(dest: jax.Array, valid: jax.Array, n_nodes: int,
-                          align: int = 8, row_bytes: int = 64,
-                          allow_ppermute: bool = True,
-                          node_ids: Optional[np.ndarray] = None,
-                          floor: Optional[np.ndarray] = None
-                          ) -> MeshRaggedSpec:
-    """Measure traffic and build the mesh-ragged plan for one call.
+                          n_chips: Optional[int] = None, align: int = 8,
+                          row_bytes: int = 64,
+                          executor: Optional[str] = None,
+                          floor: Optional[Tuple] = None) -> MeshRaggedSpec:
+    """Measure one call's traffic and build its two-level mesh plan.
 
-    dest/valid: *concrete* global (N, q) arrays — on the single-controller
-    client these carry every node's row, so the host-side max below IS the
-    psum of the per-node ``chunk_router`` histograms that a
-    multi-controller deployment would run on-fabric.  Produces
+    dest/valid: *concrete* global (N, q) arrays, copied to the host in
+    one sync — on the single-controller client they carry every node's
+    row, so the host histogram IS the psum of the per-node histograms a
+    multi-controller deployment would reduce on the fabric.  With
+    ``L = N / n_chips`` node rows per chip (``n_chips`` defaults to N:
+    a node per chip), the (source, destination) histogram gives
 
-    * per-destination **budgets** (the padded path's ``bmax``), and
-    * per-shift **round widths** ``w_k = max_i hist[i, (i + k) mod N]``
-      (the ppermute path: in round k node i talks only to node i+k, so
-      only the diagonal-k maximum needs reserving),
+    * ``view``: the most rows any node receives;
+    * the padded width: the widest chip pair with traffic;
+    * the ring widths: per chip offset k, the widest ``c → c + k`` pair,
 
-    both quantized like ``plan_ragged_spec`` (same jit-shape-space
-    argument; ``floor`` raises the per-destination budgets AND the
-    matching diagonals).  The executor is picked by the measured fabric
-    cost model: ``row_bytes`` (bytes per exchanged column) converts the
-    column counts to bytes for ``exchange_select.pick_mesh_executor``;
-    ``allow_ppermute=False`` forces the padded plan (the client sets it
-    when nodes aren't 1:1 with devices — ``ppermute`` rotates devices).
-
-    ``node_ids`` maps row index → global rank (identity when None, which
-    matches both the stacked layout and the client's global view).
+    each quantized by ``_mesh_width`` and raised to ``floor`` (the
+    ``sizes`` of an earlier spec: the client's running floor, so a
+    steady workload converges to one key).  ``executor`` forces a
+    transport; ``None`` picks it from the measured fabric cost model
+    (``exchange_select.pick_mesh_executor``: one padded ``all_to_all``
+    of ``n_chips`` segments against one ``ppermute`` per offset with
+    traffic), ``row_bytes`` converting rows to bytes.
     """
     from repro.core import exchange_select
-    # host copies first: dest may be the node-sharded output of a mesh op,
-    # and a Pallas kernel cannot take a sharded array outside shard_map
     with obs.span("client.sync.spec", cat="client"):
-        valid, dest = np.asarray(valid), np.asarray(dest)
-    d = jnp.asarray(np.where(valid, dest.astype(np.int32), n_nodes))
-    q = d.shape[1]
-    hist = histogram_rows2d(d, n_bins=n_nodes + 1)[:, :n_nodes]
-    with obs.span("client.sync.spec", cat="client"):
-        hist = np.asarray(hist)
-    if hist.shape[0] == 0:
-        hist = np.zeros((1, n_nodes), np.int64)
-    budgets = _quantize(hist.max(axis=0), q, align, floor)
-    ranks = (np.arange(hist.shape[0]) if node_ids is None
-             else np.asarray(node_ids)).astype(np.int64)
-    # w_k: the widest (source → source+k) run over all sources
-    widths = np.zeros(n_nodes, np.int64)
-    for i, r in enumerate(ranks):
-        k = (np.arange(n_nodes) - r) % n_nodes        # dest d ↦ round k
-        np.maximum.at(widths, k, hist[i])
-    widths = _quantize(widths, q, align,
-                       None if floor is None else _ragged_floor_diag(
-                           np.asarray(floor), ranks, n_nodes))
-    executor = "padded"
-    if allow_ppermute:
-        executor = exchange_select.pick_mesh_executor(
-            n_nodes, int(budgets.max(initial=0)) * n_nodes * row_bytes,
-            [int(w) * row_bytes for w in widths[1:] if w > 0])
-    return MeshRaggedSpec(tuple(int(b) for b in budgets),
-                          tuple(int(w) for w in widths), executor)
-
-
-def _ragged_floor_diag(floor: np.ndarray, ranks: np.ndarray,
-                       n_nodes: int) -> np.ndarray:
-    """Per-destination floor folded onto the shift-round diagonals."""
-    out = np.zeros(n_nodes, np.int64)
-    for r in ranks:
-        k = (np.arange(n_nodes) - r) % n_nodes
-        np.maximum.at(out, k, floor)
-    return out
-
+        valid, dest = jax.device_get((valid, dest))
+    valid = np.asarray(valid, bool)
+    dest = np.asarray(dest).astype(np.int64)
+    rows, q = dest.shape
+    N = n_nodes
+    C = N if n_chips is None else int(n_chips)
+    if N % C:
+        raise ValueError(f"{N} nodes do not split over {C} chips")
+    L = N // C
+    src = np.broadcast_to(np.arange(rows)[:, None], dest.shape)
+    hist = np.bincount(src[valid] * N + dest[valid],
+                       minlength=rows * N).reshape(rows, N)
+    full = np.zeros((N, N), np.int64)
+    full[:rows] = hist
+    pair = full.reshape(C, L, C, L).sum(axis=(1, 3))       # (src, dst) chip
+    chip_of = np.arange(C)
+    off_pairs = pair[chip_of[:, None] != chip_of[None, :]]
+    ring = np.array([_mesh_width(pair[chip_of, (chip_of + k) % C], L * q,
+                                 align) for k in range(1, C)], np.int64)
+    view = _mesh_width(full.sum(axis=0), N * q, align)
+    padded = _mesh_width(off_pairs, L * q, align)
+    if floor is not None:
+        view = max(view, floor[0])
+        padded = max(padded, floor[1])
+        ring = np.maximum(ring, np.asarray(floor[2], np.int64))
+    if executor is None:
+        executor = "padded"
+        if C > 1 and padded:
+            executor = exchange_select.pick_mesh_executor(
+                N, C * padded * row_bytes,
+                [int(w) * row_bytes for w in ring if w > 0])
+    # per-node summaries (planner and tests only; never a jit key)
+    budgets = _quantize(hist.max(axis=0) if rows else np.zeros(N, int),
+                        q, align, None)
+    k_of = (np.arange(N)[None, :] - np.arange(rows)[:, None]) % N
+    node_rounds = np.zeros(N, np.int64)
+    np.maximum.at(node_rounds, k_of.reshape(-1), hist.reshape(-1))
+    node_rounds = _quantize(node_rounds, q, align, None)
+    same = np.kron(np.eye(C, dtype=bool), np.ones((L, L), bool))
+    return MeshRaggedSpec(
+        "padded", C, int(view), (int(padded),) if C > 1 else (),
+        budgets=tuple(int(b) for b in budgets),
+        round_widths=tuple(int(w) for w in node_rounds),
+        local_rows=int(full[same].sum()),
+        fabric_rows=int(full[~same].sum()),
+        sizes=(int(view), int(padded), tuple(int(w) for w in ring))
+    ).with_executor(executor)
 
 # ---------------------------------------------------------------------------
 # exchange configuration (trace-time static, hashable)
@@ -538,8 +585,9 @@ class ExchangePlan:
       (-1 = unserved this round), consumed by ``compact_collect_flat``;
     * ``overflow`` — (L,) valid requests beyond this plan's budgets
       (feeds the carry predicate; 0 by construction for measured plans);
-    * ``recv_perm``/``inv_perm`` — the ppermute plan's round-order ↔
-      source-major receive permutations.
+    * ``aux`` — the mesh split plan's own arrays: this chip's index, the
+      local-row mask and destination rows, the fabric row tags, and the
+      receive view that ``send`` builds and ``collect`` inverts.
     """
 
     dest: jax.Array
@@ -547,8 +595,7 @@ class ExchangePlan:
     send_idx: Optional[jax.Array] = None
     reply_idx: Optional[jax.Array] = None
     overflow: Optional[jax.Array] = None
-    recv_perm: Optional[jax.Array] = None
-    inv_perm: Optional[jax.Array] = None
+    aux: Optional[Dict[str, object]] = None
 
 
 def _compact_plan(dest: jax.Array, valid: jax.Array, n_nodes: int,
@@ -791,12 +838,7 @@ class DenseExecutor:
 
 @dataclass(frozen=True)
 class UniformExecutor:
-    """Jit-static per-destination budget B — the ``all_to_all`` shape.
-
-    Doubles as the mesh "padded" ragged plan when ``budget`` is the
-    measured global-max ``bmax`` (``carry_budget=0``: overflow is
-    impossible by construction, so the carry round is statically elided).
-    """
+    """Jit-static per-destination budget B — the ``all_to_all`` shape."""
 
     n_nodes: int
     budget: int
@@ -870,27 +912,32 @@ class RaggedExecutor:
 
 
 @dataclass(frozen=True)
-class PermuteExecutor:
-    """Segmented ``ppermute`` exchange: N−1 shift rounds, measured widths.
+class MeshSplitExecutor:
+    """Two-level exchange of a :class:`MeshRaggedSpec` — the padded form.
 
-    Round k ships only what any node sends to its rank+k neighbour
-    (``spec.round_widths[k]``), so a skewed destination histogram pays
-    for its hot (source, destination) pair once instead of padding every
-    pair to the global max; round 0 — self traffic, e.g. the node-local
-    half of a hybrid batch — never crosses the fabric.  Received columns
-    are re-permuted to source-major order before the table apply, so the
-    arrival order (hence every digest) is bit-for-bit the dense path's.
+    The engine's rows are ``G`` groups of ``L = N / n_chips`` node rows:
+    one group (a chip) under ``shard_map``, every chip at once on the
+    stacked backend, where the hooks stand in for the collectives
+    (``stacked_exchange`` swaps the chip axes, ``stacked_shift`` rolls
+    them).  Per chip:
 
-    ``pipeline=True`` (default) software-pipelines the shift rounds with
-    the ragx double-buffer discipline: each round's send buffer is a
-    *load* (the ``chunk_pack`` gather) and its collective a *store*; the
-    loop keeps one round of lookahead — round k+1's load is issued
-    before round k's store — with a one-round prologue (first load) and
-    epilogue (last store).  Every round then depends only on its own
-    gather instead of one fused all-rounds gather, so the scheduler can
-    run round k's collective while round k+1 packs.  Identical values
-    either way; ``pipeline=False`` keeps the synchronous single-gather
-    structure for A/B benchmarking.
+    * **plan** — each valid row is local (destination on this chip) or
+      fabric; fabric rows are stably sorted into the send segments, so a
+      segment holds its rows in source-major order, and each fabric row
+      learns the send column its reply comes back to;
+    * **send** — under an ``exchange.split`` span the fabric block is
+      gathered (its occupancy column carries ``1 +`` the destination's
+      row on the receiving chip) and shipped (``_ship``); then every
+      node's receive view is gathered from the chip's own local rows and
+      the received fabric rows, in source-major order, ``view`` rows;
+    * **collect** — replies leave the view the way their requests came
+      in: local ones by a gather, fabric ones back over the same
+      transport into the send layout, then scattered to request slots.
+
+    The padded form ships one ``all_to_all`` over the chips, one segment
+    of ``spec.widths[0]`` rows per destination chip (the self segment is
+    empty and stays on the chip).  The measured widths cover every row,
+    so the plan is lossless by construction and no carry round is traced.
     """
 
     n_nodes: int
@@ -899,82 +946,232 @@ class PermuteExecutor:
     drop: bool = False
     pipeline: bool = True
 
+    # ---- geometry and segments --------------------------------------------
+    def _geometry(self, rows: int) -> Tuple[int, int, int]:
+        C = self.spec.n_chips
+        if self.n_nodes % C or rows % (self.n_nodes // C):
+            raise ValueError(
+                f"a {C}-chip mesh spec cannot split {rows} local rows of "
+                f"{self.n_nodes} nodes into whole chips")
+        L = self.n_nodes // C
+        return C, L, rows // L
+
+    def _row_segment(self, off: jax.Array, me: jax.Array) -> jax.Array:
+        """Send segment of each fabric row (its destination chip)."""
+        return (me[:, None] + off) % self.spec.n_chips
+
+    # ---- the executor interface ---------------------------------------------
     def plan(self, dest: jax.Array, valid: jax.Array,
              client: Optional[jax.Array] = None) -> ExchangePlan:
-        """Routing plan over the shift-round diagonals.
+        """Split rows into the local and fabric blocks; place the fabric
+        rows in their send segments.
 
-        ``client``: (L, 1) global ranks of the local rows — round k's
-        target for row of rank r is ``(r + k) mod N``, which is also how
-        a received column's source is recovered on the other side.
-        Required: without the true ranks every shift round would
-        mis-route under shard_map (where L=1 and the row index is NOT
-        the rank), so a missing ``client`` is an error, not a default.
-        """
+        ``client``: the local rows' global ranks, which name this chip
+        (required, as for every mesh plan: under ``shard_map`` the row
+        index is not the rank)."""
         if client is None:
             raise ValueError(
-                "PermuteExecutor.plan needs the local rows' global ranks "
+                "mesh split plans need the local rows' global ranks "
                 "(client); engine entry points thread them — pass "
                 "_client_ranks(L, node_ids) when calling run_exchange "
-                "with a ppermute spec directly")
-        N, spec = self.n_nodes, self.spec
-        L, q = dest.shape
-        rank = client[:, 0]                                      # (L,)
-        d = jnp.where(valid, dest, N).astype(jnp.int32)
-        order = jnp.argsort(d, axis=1).astype(jnp.int32)         # stable
-        sd = jnp.take_along_axis(d, order, axis=1)
-        counts = histogram_rows2d(d, n_bins=N + 1)[:, :N]
-        start = jnp.cumsum(counts, axis=1) - counts              # exclusive
-        col_round = jnp.asarray(spec.col_round)                  # (S,)
-        col_pos = jnp.asarray(spec.col_pos)                      # (S,)
-        w_arr = jnp.asarray(np.asarray(spec.round_widths + (0,), np.int32))
-        off_arr = jnp.asarray(spec.offsets)                      # (N+1,)
-        if spec.total:
-            t = (rank[:, None] + col_round[None, :]) % N         # (L, S)
-            cnt = jnp.take_along_axis(counts, t, axis=1)
-            pos = jnp.take_along_axis(start, t, axis=1) + col_pos[None, :]
-            src = jnp.take_along_axis(order, jnp.clip(pos, 0, q - 1),
-                                      axis=1)
-            send_idx = jnp.where(col_pos[None, :] < cnt, src, -1)
-            # receive side: the column shipped in round k came from rank−k;
-            # stable-sort columns by source to restore dense arrival order
-            src_rank = (rank[:, None] - col_round[None, :]) % N
-            recv_perm = jnp.argsort(src_rank, axis=1).astype(jnp.int32)
-            rows = jnp.broadcast_to(jnp.arange(L)[:, None],
-                                    (L, spec.total))
-            inv_perm = jnp.zeros((L, spec.total), jnp.int32).at[
-                rows, recv_perm].set(jnp.broadcast_to(
-                    jnp.arange(spec.total, dtype=jnp.int32)[None, :],
-                    (L, spec.total)))
-        else:
-            send_idx = jnp.zeros((L, 0), jnp.int32)
-            recv_perm = inv_perm = jnp.zeros((L, 0), jnp.int32)
-        # a request with destination d rides round (d − rank) mod N
-        k_sorted = jnp.where(sd < N, (sd - rank[:, None]) % N, N)
-        startx = jnp.concatenate(
-            [start, jnp.zeros((L, 1), jnp.int32)], axis=1)
-        run_rank = jnp.arange(q, dtype=jnp.int32)[None, :] - \
-            jnp.take_along_axis(startx, sd, axis=1)
-        slot = jnp.where((sd < N) & (run_rank < w_arr[k_sorted]),
-                         off_arr[k_sorted] + run_rank, -1)
-        rows = jnp.broadcast_to(jnp.arange(L)[:, None], (L, q))
-        reply_idx = jnp.zeros((L, q), jnp.int32).at[rows, order].set(slot)
-        # overflow (0 by construction when spec measured this dest/valid)
-        darange = jnp.arange(N, dtype=jnp.int32)
-        cap = w_arr[(darange[None, :] - rank[:, None]) % N]
-        overflow = (counts - jnp.minimum(counts, cap)).sum(
+                "with a mesh spec directly")
+        C, L, G = self._geometry(dest.shape[0])
+        q = dest.shape[1]
+        P = L * q
+        me = (client[::L, 0] // L).astype(jnp.int32)             # (G,)
+        v = valid.reshape(G, P)
+        d = jnp.where(v, dest.reshape(G, P), 0).astype(jnp.int32)
+        off = jnp.where(v, (d // L - me[:, None]) % C, -1)
+        fab = off > 0
+        seg_w = self.spec.segments
+        nseg = len(seg_w)
+        seg = jnp.where(fab, self._row_segment(off, me), nseg)
+        seg_wx = jnp.asarray(np.asarray(seg_w + (0,), np.int32))
+        seg_base = jnp.asarray(np.concatenate(
+            [[0], np.cumsum(seg_w)]).astype(np.int32))
+        order = jnp.argsort(seg, axis=1, stable=True).astype(jnp.int32)
+        counts = jnp.sum(seg[:, :, None] == jnp.arange(nseg + 1)[None, None],
+                         axis=1).astype(jnp.int32)               # (G, nseg+1)
+        start = jnp.cumsum(counts, axis=1) - counts
+        col_seg = np.repeat(np.arange(nseg), seg_w).astype(np.int32)
+        col_rank = (np.concatenate([np.arange(w) for w in seg_w])
+                    if nseg else np.zeros(0)).astype(np.int32)
+        pos = start[:, col_seg] + col_rank[None, :]
+        src = jnp.take_along_axis(order, jnp.clip(pos, 0, P - 1), axis=1)
+        send_idx = jnp.where(col_rank[None, :] < counts[:, col_seg], src, -1)
+        # the send column each fabric row's reply comes back to
+        sseg = jnp.take_along_axis(seg, order, axis=1)
+        rank = jnp.arange(P, dtype=jnp.int32)[None, :] - \
+            jnp.take_along_axis(start, sseg, axis=1)
+        slot = jnp.where((sseg < nseg) & (rank < seg_wx[sseg]),
+                         seg_base[sseg] + rank, -1)
+        rows = jnp.broadcast_to(jnp.arange(G)[:, None], (G, P))
+        reply_idx = jnp.zeros((G, P), jnp.int32).at[rows, order].set(slot)
+        overflow = (fab & (reply_idx < 0)).reshape(G * L, q).sum(
             axis=1).astype(jnp.int32)
+        tag = jnp.where(fab, d % L + 1, 0)
+        send_tag = jnp.where(
+            send_idx >= 0,
+            jnp.take_along_axis(tag, jnp.maximum(send_idx, 0), axis=1), 0)
         return ExchangePlan(dest, valid, send_idx, reply_idx, overflow,
-                            recv_perm, inv_perm)
+                            aux={"me": me, "local": off == 0,
+                                 "dloc": d % L, "send_tag": send_tag,
+                                 "geometry": (C, L, G, q)})
 
-    def _segments(self):
-        off = self.spec.offsets
-        return [(k, int(off[k]), int(w))
-                for k, w in enumerate(self.spec.round_widths) if w > 0]
+    def _pack(self, flat: jax.Array, plan: ExchangePlan, lo: int = 0,
+              hi: Optional[int] = None) -> jax.Array:
+        """Gather send columns [lo, hi) of the fabric block; the
+        occupancy column carries the destination row tag."""
+        idx = plan.send_idx[:, lo:hi]
+        buf = gather_rows_batched(flat, idx)
+        return buf.at[..., -1].set(plan.aux["send_tag"][:, lo:hi])
+
+    def send(self, plan: ExchangePlan, fields: jax.Array,
+             exchange: Callable, shift: Callable
+             ) -> Tuple[jax.Array, jax.Array]:
+        """Ship the fabric block, then gather each node's receive view."""
+        C, L, G, q = plan.aux["geometry"]
+        P, F = L * q, fields.shape[-1]
+        me = plan.aux["me"]
+        flat = fields.reshape(G, P, F)
+        with obs.span("exchange.split", cat="exchange",
+                      executor=self.spec.executor, chips=C, rows=L):
+            if self.spec.segments:
+                recv_f, src_chip, pos = self._ship_all(flat, plan, exchange,
+                                                       shift)
+            else:
+                recv_f = jnp.zeros((G, 0, F), fields.dtype)
+                src_chip = pos = jnp.zeros((G, 0), jnp.int32)
+            S = recv_f.shape[1]
+            view = self.spec.view
+            pad = max(0, view - (P + S))
+            big = max(P, S, 1) + 1
+            tag = recv_f[..., -1]
+            ok = jnp.concatenate([plan.aux["local"], tag > 0,
+                                  jnp.zeros((G, pad), bool)], axis=1)
+            dl = jnp.concatenate([plan.aux["dloc"], tag - 1,
+                                  jnp.zeros((G, pad), jnp.int32)], axis=1)
+            # source-major order: chip, then the source chip's own order
+            # (local rows by slot; a fabric segment arrives in it)
+            key = jnp.concatenate(
+                [me[:, None] * big + jnp.arange(P)[None, :],
+                 src_chip * big + pos,
+                 jnp.zeros((G, pad), jnp.int32)], axis=1)
+            sel = ok[:, None, :] & (dl[:, None, :] ==
+                                    jnp.arange(L)[None, :, None])
+            sk = jnp.where(sel, key[:, None, :], (C + 1) * big)
+            idx = jnp.argsort(sk, axis=-1)[..., :view].astype(jnp.int32)
+            rvalid = jnp.arange(view)[None, None, :] < \
+                sel.sum(axis=-1)[..., None]
+            cand = jnp.concatenate(
+                [flat, recv_f, jnp.zeros((G, pad, F), fields.dtype)],
+                axis=1)
+            recv = jnp.take_along_axis(
+                cand, idx.reshape(G, L * view)[..., None], axis=1)
+        plan.aux.update(view_idx=idx.reshape(G, L * view),
+                        view_ok=rvalid.reshape(G, L * view),
+                        n_cand=P + S + pad, n_fabric=S)
+        recv = recv.reshape(G * L, view, F)
+        return recv[..., :-1], rvalid.reshape(G * L, view)
+
+    def _ship_all(self, flat, plan, exchange, shift):
+        """Pack and ship the fabric block: one ``all_to_all`` over chips,
+        (G, C·W, F) by destination chip in, by source chip out.  Returns
+        the rows, each column's source chip and its position in that
+        chip's segment."""
+        G, C, W = flat.shape[0], self.spec.n_chips, self.spec.segments[0]
+        buf = self._pack(flat, plan)
+        got = exchange(buf.reshape((G, C, W) + buf.shape[2:]))
+        src = np.repeat(np.arange(C, dtype=np.int32), W)
+        pos = np.tile(np.arange(W, dtype=np.int32), C)
+        return (got.reshape(buf.shape),
+                jnp.broadcast_to(jnp.asarray(src)[None, :], (G, C * W)),
+                jnp.broadcast_to(jnp.asarray(pos)[None, :], (G, C * W)))
+
+    def collect(self, plan: ExchangePlan, reply: jax.Array,
+                exchange: Callable, shift: Callable,
+                fill: int = 0) -> jax.Array:
+        """Local replies by a gather, fabric replies home over the
+        transport, then each request slot takes its own."""
+        C, L, G, q = plan.aux["geometry"]
+        P, S = L * q, plan.aux["n_fabric"]
+        rest = reply.shape[2:]
+        ext = (1,) * len(rest)
+        if self.spec.view == 0:
+            return jnp.full((G * L, q) + rest, fill, reply.dtype)
+        rep = reply.reshape((G, L * self.spec.view) + rest)
+        n_cand = plan.aux["n_cand"]
+        rows = jnp.broadcast_to(jnp.arange(G)[:, None],
+                                plan.aux["view_idx"].shape)
+        at = jnp.where(plan.aux["view_ok"], plan.aux["view_idx"], n_cand)
+        inv = jnp.full((G, n_cand), -1, jnp.int32).at[rows, at].set(
+            jnp.broadcast_to(jnp.arange(at.shape[1], dtype=jnp.int32),
+                             at.shape), mode="drop")
+        got = jnp.take_along_axis(
+            rep, jnp.maximum(inv[:, :P + S], 0).reshape(
+                (G, P + S) + ext), axis=1)
+        local = plan.aux["local"] & (inv[:, :P] >= 0)
+        out = jnp.where(local.reshape((G, P) + ext), got[:, :P], fill)
+        if S:
+            home = self._ship_home(got[:, P:], exchange, shift)
+            ri = plan.reply_idx
+            back = jnp.take_along_axis(
+                home, jnp.maximum(ri, 0).reshape((G, P) + ext), axis=1)
+            out = jnp.where((ri >= 0).reshape((G, P) + ext), back, out)
+        return out.reshape((G * L, q) + rest)
+
+    def _ship_home(self, rep, exchange, shift):
+        """Fabric replies back to their senders' send layout: the chip
+        ``all_to_all`` is its own inverse."""
+        G, C, W = rep.shape[0], self.spec.n_chips, self.spec.segments[0]
+        return exchange(rep.reshape((G, C, W) + rep.shape[2:])
+                        ).reshape(rep.shape)
+
+    def served(self, plan: ExchangePlan) -> jax.Array:
+        """Measured widths cover every request (lossless by plan)."""
+        return plan.valid
+
+
+@dataclass(frozen=True)
+class PermuteExecutor(MeshSplitExecutor):
+    """The two-level exchange's chip-ring form: ``ppermute`` shifts.
+
+    Segment ``k`` holds the rows bound ``k`` chips along the ring, at the
+    measured width ``spec.widths[k - 1]``; only offsets with traffic
+    ship, so an op whose fabric rows all go to the next chip (a read of
+    the next node's files, where 7 of a chip's 8 nodes read on the chip)
+    ships one segment of its real rows instead of a padded segment to
+    every chip.  Received columns carry their source chip, so the
+    receive view restores source-major order for any number of node rows
+    per chip.
+
+    ``pipeline=True`` (default) software-pipelines the shifts with the
+    ragx double-buffer discipline: each segment's send buffer is a
+    *load* (its ``chunk_pack`` gather) and its collective a *store*; the
+    loop keeps one segment of lookahead — segment k+1's load is issued
+    before segment k's store — so the scheduler can run one shift while
+    the next segment packs.  Identical values either way;
+    ``pipeline=False`` gathers every segment before the first shift.
+    """
+
+    def _row_segment(self, off: jax.Array, me: jax.Array) -> jax.Array:
+        """Send segment of each fabric row: its chip offset's index among
+        the offsets with traffic (offsets of width 0 map past the end)."""
+        seg_of = np.full(self.spec.n_chips, len(self.spec.offsets),
+                         np.int32)
+        for i, k in enumerate(self.spec.offsets):
+            seg_of[k] = i
+        return jnp.asarray(seg_of)[off]
+
+    def _rounds(self):
+        base = np.concatenate([[0], np.cumsum(self.spec.segments)])
+        return [(k, int(base[i]), int(w)) for i, (k, w) in
+                enumerate(zip(self.spec.offsets, self.spec.segments))]
 
     def _ship_rounds(self, segments, load_fn, store_fn):
         """Software-pipelined round loop (shared by send and collect).
 
-        ``load_fn(k, off, w)`` packs round k's buffer (the chunk gather
+        ``load_fn(k, off, w)`` packs segment k's buffer (the chunk gather
         on the send side, the reply slice on the collect side);
         ``store_fn(k, buf)`` ships it through the collective.  With
         ``pipeline`` on, the loop keeps ragx-style one-round lookahead —
@@ -1006,67 +1203,33 @@ class PermuteExecutor:
                 parts.append(store_fn(last_k, load_tag))
         return parts
 
-    def send(self, plan: ExchangePlan, fields: jax.Array,
-             exchange: Callable, shift: Callable
-             ) -> Tuple[jax.Array, jax.Array]:
-        """Pack and shift each nonzero round, restore source order.
+    def _ship_all(self, flat, plan, exchange, shift):
+        """One shift per chip offset with traffic; segment k arrives from
+        chip ``me − k``."""
+        me = plan.aux["me"]
+        C = self.spec.n_chips
+        rounds = self._rounds()
+        parts = self._ship_rounds(
+            rounds, lambda k, lo, w: self._pack(flat, plan, lo, lo + w),
+            lambda k, buf: shift(buf, k))
+        G = flat.shape[0]
+        src = jnp.concatenate(
+            [jnp.broadcast_to(((me - k) % C)[:, None], (G, w))
+             for k, _, w in rounds], axis=1)
+        pos = np.concatenate([np.arange(w) for _, _, w in rounds])
+        return (jnp.concatenate(parts, axis=1), src,
+                jnp.broadcast_to(jnp.asarray(pos, jnp.int32)[None, :],
+                                 src.shape))
 
-        Pipelined: per-round ``chunk_pack`` gathers, one round of
-        lookahead.  Synchronous: one fused gather of every round before
-        any shift (the PR-5 structure, where the first collective waits
-        on the whole pack).  Round 0 is self traffic, no collective.
-        """
-        segments = self._segments()
-        if not self.pipeline:
-            gathered = gather_rows_batched(fields, plan.send_idx)
-            parts = [gathered[:, off:off + w] if k == 0
-                     else shift(gathered[:, off:off + w], k)
-                     for k, off, w in segments]
-        else:
-            def load(k, off, w):
-                return gather_rows_batched(fields,
-                                           plan.send_idx[:, off:off + w])
-
-            def store(k, buf):
-                return buf if k == 0 else shift(buf, k)
-
-            parts = self._ship_rounds(segments, load, store)
-        if not parts:
-            L = fields.shape[0]
-            return (jnp.zeros((L, 0, fields.shape[-1] - 1), fields.dtype),
-                    jnp.zeros((L, 0), bool))
-        recv = jnp.concatenate(parts, axis=1)           # round order
-        recv = jnp.take_along_axis(recv, plan.recv_perm[..., None], axis=1)
-        return recv[..., :-1], recv[..., -1] > 0
-
-    def collect(self, plan: ExchangePlan, reply: jax.Array,
-                exchange: Callable, shift: Callable,
-                fill: int = 0) -> jax.Array:
-        """Shift each round's replies home and scatter to request slots."""
-        if self.spec.total == 0:
-            L, q = plan.reply_idx.shape
-            return jnp.full((L, q) + reply.shape[2:], fill, reply.dtype)
-        back = jnp.take_along_axis(
-            reply, plan.inv_perm.reshape(plan.inv_perm.shape +
-                                         (1,) * (reply.ndim - 2)), axis=1)
-
-        def load(k, off, w):
-            return back[:, off:off + w]
-
-        def store(k, buf):
-            return buf if k == 0 else shift(buf, -k)
-
-        parts = self._ship_rounds(self._segments(), load, store)
-        home = jnp.concatenate(parts, axis=1)           # round order
-        return compact_collect_flat(plan.reply_idx, home, fill)
-
-    def served(self, plan: ExchangePlan) -> jax.Array:
-        """Measured round widths cover every request (lossless by plan)."""
-        return plan.valid
-
+    def _ship_home(self, rep, exchange, shift):
+        """Each segment's replies shift back ``−k`` chips."""
+        parts = self._ship_rounds(
+            self._rounds(), lambda k, lo, w: rep[:, lo:lo + w],
+            lambda k, buf: shift(buf, -k))
+        return jnp.concatenate(parts, axis=1)
 
 Executor = Union[DenseExecutor, UniformExecutor, RaggedExecutor,
-                 PermuteExecutor]
+                 MeshSplitExecutor, PermuteExecutor]
 
 
 def build_executor(role: str, policy, q: int,
@@ -1084,11 +1247,11 @@ def build_executor(role: str, policy, q: int,
         return DenseExecutor(N)
     spec = config.data_spec if role == "data" else config.meta_spec
     if isinstance(spec, MeshRaggedSpec):
-        if spec.executor == "ppermute":
-            return PermuteExecutor(N, spec, pipeline=config.pipeline)
-        # padded path: uniform all_to_all at the measured global max —
-        # lossless by construction, so no carry round is traced
-        return UniformExecutor(N, max(1, spec.bmax))
+        # two-level split: measured widths, lossless by construction, so
+        # no carry round is traced
+        split = (PermuteExecutor if spec.executor == "ppermute"
+                 else MeshSplitExecutor)
+        return split(N, spec, pipeline=config.pipeline)
     if isinstance(spec, RaggedSpec):
         return RaggedExecutor(N, spec)
     B = (data_budget(policy, q, config) if role == "data"
@@ -1113,8 +1276,8 @@ def fuse_specs(data_spec, meta_spec):
     rows, which the summed budgets cover exactly — the fused plan stays
     lossless whenever both component plans were.  Only stacked
     ``RaggedSpec`` pairs need a summed spec (``ragged_exchange`` runs on
-    it); mesh padded plans fuse as two uniform budgets concatenated on
-    the ``all_to_all`` budget axis, and ppermute plans never fuse — see
+    it); uniform budgets fuse by concatenation on the ``all_to_all``
+    budget axis, and mesh split plans never fuse — see
     ``fused_write_plan``.
     """
     if isinstance(data_spec, RaggedSpec) and isinstance(meta_spec,
@@ -1185,18 +1348,17 @@ def fused_write_plan(policy, q: int, config: ExchangeConfig
     metadata rounds can ship through one collective (``fused_send``), or
     ``None`` when fusion is elided: dense kind, pipelining off, the drop
     plane (``lossless=False`` skips overflowed metadata anyway),
-    measured specs of mismatched types, a ppermute plane (fusing would
-    serialize both planes' packs behind the 2(N−1) shift rounds the
-    serial path overlaps, and the receive split is not static across
-    rounds), or any plan that could overflow into a carry round.  The
+    a mesh split plane (its receive view is built per plane from what
+    arrived, so no static split of one fused buffer exists), or any plan
+    that could overflow into a carry round.  The
     overflow rule is a parity requirement, not a performance one: a
     fused carry would re-split the metadata batch across two
     ``_meta_apply`` calls, and within-batch duplicate keys allocate
     differently in one call than in two — so only provably overflow-free
     plans fuse (measured specs, which size every segment from the actual
     histogram, or uniform budgets already at ``B = q`` on both planes).
-    The default client path measures specs, so stacked and mesh-padded
-    writes always fuse.
+    The default stacked client path measures specs, so its writes
+    always fuse.
     """
     if config.kind != "compacted" or not config.pipeline \
             or not config.lossless or q == 0:
@@ -1205,13 +1367,6 @@ def fused_write_plan(policy, q: int, config: ExchangeConfig
     N = policy.n_nodes
     ds, ms = config.data_spec, config.meta_spec
     if ds is not None or ms is not None:
-        if isinstance(ds, MeshRaggedSpec) and isinstance(ms,
-                                                         MeshRaggedSpec):
-            if ds.n_nodes != ms.n_nodes \
-                    or "ppermute" in (ds.executor, ms.executor):
-                return None
-            return (UniformExecutor(N, max(1, ds.bmax)),
-                    UniformExecutor(N, max(1, ms.bmax)))
         if isinstance(ds, RaggedSpec) and isinstance(ms, RaggedSpec) \
                 and fuse_specs(ds, ms) is not None:
             return RaggedExecutor(N, ds), RaggedExecutor(N, ms)
@@ -1238,8 +1393,8 @@ def fused_send(ex_d: Executor, plan_d: ExchangePlan, fields_d: jax.Array,
     serial two-round write while the fabric sees a single launch.
 
     Supported pairs (all ``fused_write_plan`` ever builds): two
-    ``UniformExecutor``\\ s — uniform budgets and the mesh padded plan,
-    whose segments concatenate on the static budget axis the
+    ``UniformExecutor``\\ s — uniform budgets, whose segments
+    concatenate on the static budget axis the
     ``all_to_all`` splits — and two stacked ``RaggedExecutor``\\ s,
     whose static per-destination offsets make the packed interleave and
     the receive split constant index maps (``_fused_pack_cols`` /
@@ -1394,13 +1549,12 @@ def run_exchange(role: str, policy, config: ExchangeConfig,
 # modeled footprint
 # ---------------------------------------------------------------------------
 def _spec_cols(spec, n_nodes: int, uniform_b: int) -> int:
-    """Exchanged send-buffer columns per source row for one plan."""
+    """Exchanged send-buffer columns of one plan, all source rows."""
     if isinstance(spec, MeshRaggedSpec):
-        return (spec.exchanged_cols if spec.executor == "ppermute"
-                else n_nodes * max(1, spec.bmax))
+        return spec.n_chips * spec.exchanged_cols
     if isinstance(spec, RaggedSpec):
-        return spec.total
-    return n_nodes * uniform_b
+        return n_nodes * spec.total
+    return n_nodes * n_nodes * uniform_b
 
 
 def exchange_footprint(policy, q: int, words: int,
@@ -1412,9 +1566,8 @@ def exchange_footprint(policy, q: int, words: int,
     benchmark harness converts these to bytes.  Dense buffers carry q slots
     per (src, dst) pair; uniform compacted ones the per-destination budget;
     ragged ones the measured packed columns per source row — Σbᵢ for the
-    stacked plan, N·bmax for the mesh padded plan, and the Σ of the
-    nonzero off-diagonal round widths for the ppermute plan (round 0 is
-    node-local and crosses nothing).  The ``*_carry_elems`` fields are
+    stacked plan — and mesh split plans the fabric rows each chip ships
+    (same-chip rows cross nothing).  The ``*_carry_elems`` fields are
     the worst case of the cond-skipped lossless carry round — 0 when no
     overflow occurs (the common case) and 0 by construction for measured
     ragged plans and lossless B=q.
@@ -1434,19 +1587,19 @@ def exchange_footprint(policy, q: int, words: int,
     else:
         bd = bm = q
     cols_d = (_spec_cols(config.data_spec, N, bd)
-              if config.kind == "compacted" else N * bd)
+              if config.kind == "compacted" else N * N * bd)
     cols_m = (_spec_cols(config.meta_spec, N, bm)
-              if config.kind == "compacted" else N * bm)
+              if config.kind == "compacted" else N * N * bm)
     w_meta, w_wr, w_rd = (4 + 1) + 3, (2 + words + 1), (2 + 1) + (words + 1)
     w_fused = max(2 + words, 4) + 1           # widest plane row + mask
-    meta = N * cols_m * w_meta                # op/key/size/loc+mask → replies
-    write = N * cols_d * w_wr + meta          # keys+payload+mask, then meta
-    read = N * cols_d * w_rd
+    meta = cols_m * w_meta                    # op/key/size/loc+mask → replies
+    write = cols_d * w_wr + meta              # keys+payload+mask, then meta
+    read = cols_d * w_rd
     carry = {"write_carry_elems": 0, "read_carry_elems": 0,
              "meta_carry_elems": 0}
     fplan = fused_write_plan(policy, q, config)
     if fplan is not None:
-        write = N * (cols_d + cols_m) * w_fused     # one launch, no replies
+        write = (cols_d + cols_m) * w_fused         # one launch, no replies
     if config.kind == "compacted" and config.lossless:
         cd = 0 if config.data_spec is not None else _carry_budget(q, bd)
         cm = 0 if config.meta_spec is not None else _carry_budget(q, bm)

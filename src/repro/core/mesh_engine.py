@@ -1,18 +1,22 @@
 """shard_map deployment of the burst-buffer engine on a device mesh.
 
-The stacked engine (burst_buffer.py) runs unchanged per-node under
-``shard_map``: the node axis is sharded 1-per-device, global ranks come from
-``axis_index`` and the exchange becomes ``jax.lax.all_to_all`` over the
-``node`` axis.  This is the production data plane behind the mesh backend of
-``BBClient`` (client.py) — construct ``BBClient(policy, mesh)`` rather than
-calling ``build_mesh_ops`` directly.
+The stacked engine (burst_buffer.py) runs unchanged per-chip under
+``shard_map``: the node axis is sharded over the devices, each holding
+``N / n_devices`` node rows, global ranks come from ``axis_index`` and the
+exchange becomes ``jax.lax.all_to_all`` over the ``node`` axis.  This is
+the production data plane behind the mesh backend of ``BBClient``
+(client.py) — construct ``BBClient(policy, mesh)`` rather than calling
+``build_mesh_ops`` directly.
 
 Ragged plans on the mesh: a packed :class:`~repro.core.exchange_plan.
 RaggedSpec` cannot cross ``all_to_all`` (uniform splits) and is rejected
 here, but a measured :class:`~repro.core.exchange_plan.MeshRaggedSpec`
-can — its "padded" form rides the ordinary ``all_to_all`` at the global
-max budget, and its "ppermute" form runs the segmented shift rounds
-through :func:`build_mesh_shift`'s real ``lax.ppermute`` collective.
+can: it splits every exchange in two, rows for a node on the same chip
+move on the chip, and only rows for other chips cross the fabric,
+compacted per chip pair — in one ``all_to_all`` over the chips
+(``mesh_exchange`` on a (1, chips, width) buffer) in its "padded" form,
+or in one :func:`build_mesh_shift` ``lax.ppermute`` per chip offset with
+traffic in its "ppermute" form.  Any number of node rows per chip.
 
 Migration note: the pre-policy ``make_mesh_ops(mesh, params)`` entry point is
 gone.  ``build_mesh_ops(mesh, policy)`` returns ops that additionally take
@@ -58,20 +62,22 @@ def mesh_exchange(x: jax.Array) -> jax.Array:
     """
     y = jax.lax.all_to_all(x, NODE_AXIS, split_axis=1, concat_axis=0,
                            tiled=True)
-    # y: (N * L, ?, s, ...) with local leading = N, second = L
-    return jnp.swapaxes(y, 0, 1) if y.shape[0] != x.shape[0] else y
+    # y: (N_src, L_dst, s, ...): source-major over every device's rows.
+    # The swap is always due — also on a one-device mesh, where the
+    # collective is the identity but the rows still change sides
+    return jnp.swapaxes(y, 0, 1)
 
 
 def build_mesh_shift(n_dev: int) -> Callable:
-    """The mesh twin of ``exchange_plan.stacked_shift``: a k-step rotation.
+    """The mesh twin of ``exchange_plan.stacked_shift``: a k-chip rotation.
 
     Returns ``shift(x, k)`` running ``lax.ppermute`` with the
-    ``[(i, (i + k) % N) for i]`` ring permutation over the node axis —
-    device ``i``'s buffer lands on device ``(i + k) mod N``, exactly what
-    ``jnp.roll(x, k, axis=0)`` does to the stacked layout.  Only valid
-    when nodes are 1:1 with devices (``build_mesh_ops`` enforces this for
-    ppermute specs — rotating a device that holds several node rows would
-    rotate them together).
+    ``[(i, (i + k) % n_dev) for i]`` ring permutation over the devices —
+    device ``i``'s buffer lands on device ``(i + k) mod n_dev``, what
+    ``jnp.roll(x, k, axis=0)`` does to a stacked array of one group per
+    chip.  The buffer is a chip's fabric segment for offset ``k``
+    (``exchange_plan.PermuteExecutor``), so it holds rows of whichever of
+    the chip's node rows send that way: any number of node rows per chip.
     """
 
     def shift(x: jax.Array, k: int) -> jax.Array:
@@ -97,7 +103,7 @@ def mesh_global_sum(x: jax.Array) -> jax.Array:
     return jax.lax.psum(jnp.sum(x), NODE_AXIS)
 
 
-def _check_specs(config: bb.ExchangeConfig, local_n: int) -> None:
+def _check_specs(config: bb.ExchangeConfig, n_dev: int) -> None:
     """Reject exchange specs the mesh collectives cannot carry."""
     for spec in (config.data_spec, config.meta_spec):
         if isinstance(spec, RaggedSpec):
@@ -107,13 +113,10 @@ def _check_specs(config: bb.ExchangeConfig, local_n: int) -> None:
                 "use a MeshRaggedSpec (padded or ppermute plan) or "
                 "uniform budgets (the lossless carry round covers "
                 "overflow)")
-        if isinstance(spec, MeshRaggedSpec) and \
-                spec.executor == "ppermute" and local_n != 1:
+        if isinstance(spec, MeshRaggedSpec) and spec.n_chips != n_dev:
             raise ValueError(
-                "the ppermute segmented exchange rotates the device ring; "
-                f"with {local_n} node rows per device the rotation would "
-                "move them together — use the padded plan (bmax "
-                "all_to_all) when nodes aren't 1:1 with devices")
+                f"a MeshRaggedSpec planned for {spec.n_chips} chips cannot "
+                f"run on a {n_dev}-device mesh")
 
 
 @obs.trace_span("mesh.build_ops", cat="build")
@@ -128,10 +131,10 @@ def build_mesh_ops(mesh: Mesh, policy,
     sharded over the ``node`` axis on their leading dim.  ``config``
     selects the exchange data plane; the planner (exchange_plan.py)
     resolves it per phase, and all transports — dense bucketize, uniform
-    all_to_all, padded mesh-ragged, ppermute segmented (whose shift
-    rounds ``run_exchange`` software-pipelines when ``config.pipeline``)
-    — run through the same ``mesh_exchange``/``build_mesh_shift``
-    collectives.
+    all_to_all, the two-level mesh split with its padded chip
+    ``all_to_all`` or its chip-ring shifts (software-pipelined when
+    ``config.pipeline``) — run through the same
+    ``mesh_exchange``/``build_mesh_shift`` collectives.
 
     The four keep the state they are given; their ``owned`` twins
     (``bb.EngineOps``) donate it to write and meta, so each device's
@@ -144,7 +147,7 @@ def build_mesh_ops(mesh: Mesh, policy,
     assert policy.n_nodes % n_dev == 0
     local_n = policy.n_nodes // n_dev
     req_spec = PS(NODE_AXIS)
-    _check_specs(config, local_n)
+    _check_specs(config, n_dev)
     shift = build_mesh_shift(n_dev)
 
     def _write(state, mode, ph, cid, payload, valid):
@@ -251,7 +254,7 @@ def build_mesh_probe(mesh: Mesh, policy,
     assert policy.n_nodes % n_dev == 0
     local_n = policy.n_nodes // n_dev
     req_spec = PS(NODE_AXIS)
-    _check_specs(config, local_n)
+    _check_specs(config, n_dev)
     shift = build_mesh_shift(n_dev)
 
     def _probe(state, mode, ph, valid):

@@ -14,7 +14,7 @@ import pytest
 from repro.core import burst_buffer as bb
 from repro.core.client import BBClient, BBRequest, _build_stacked_ops
 from repro.core.layouts import (LayoutMode, LayoutParams, f_data, f_meta_f,
-                                str_hash)
+                                route_data, route_meta, str_hash)
 from repro.core.policy import LayoutPolicy
 
 from test_policy import SEED_DIGESTS, _digest
@@ -870,28 +870,51 @@ def test_mesh_lossless_carry_parity():
 
 def test_mesh_rejects_packed_ragged_specs():
     """build_mesh_ops must refuse PACKED ragged configs (all_to_all needs
-    uniform splits) while accepting the mesh-ragged plans; the client now
-    keeps ragged planning on, producing MeshRaggedSpec configs instead."""
+    uniform splits) and mesh plans made for another chip count, while
+    accepting the mesh-ragged plans — the ppermute one too when a device
+    holds several node rows; the client keeps ragged planning on,
+    producing MeshRaggedSpec configs instead."""
     from repro.core.mesh_engine import build_mesh_ops, make_node_mesh
     policy = LayoutPolicy.uniform(LayoutMode.DIST_HASH, 1)
     spec = bb.RaggedSpec((1,))
     with pytest.raises(ValueError, match="ragged"):
         build_mesh_ops(make_node_mesh(1), policy,
                        bb.ExchangeConfig("compacted", data_spec=spec))
-    # a MeshRaggedSpec is carried fine (padded path = uniform bmax)
-    mspec = bb.MeshRaggedSpec((1,), (1,), "padded")
+    # a MeshRaggedSpec is carried fine (the split with no fabric block)
+    mspec = bb.MeshRaggedSpec("padded", 1, 8, ())
     build_mesh_ops(make_node_mesh(1), policy,
                    bb.ExchangeConfig("compacted", data_spec=mspec))
-    # the ppermute plan needs nodes 1:1 with devices
+    with pytest.raises(ValueError, match="4 chips"):
+        build_mesh_ops(make_node_mesh(1), policy, bb.ExchangeConfig(
+            "compacted", data_spec=bb.MeshRaggedSpec("padded", 4, 8, (8,))))
+    # the ppermute plan runs with two node rows on one device: the split
+    # keeps both rows' traffic on the chip, and the write is the dense one
     pol2 = LayoutPolicy.uniform(LayoutMode.DIST_HASH, 2)
-    pspec = bb.MeshRaggedSpec((1, 1), (1, 1), "ppermute")
-    with pytest.raises(ValueError, match="ppermute"):
-        build_mesh_ops(make_node_mesh(1), pol2,
-                       bb.ExchangeConfig("compacted", data_spec=pspec))
+    rng = np.random.RandomState(3)
+    ph = jnp.asarray(rng.randint(1, 999, (2, 8)), jnp.int32)
+    cid = jnp.zeros((2, 8), jnp.int32)
+    pay = jnp.asarray(rng.randint(0, 99, (2, 8, 4)), jnp.int32)
+    valid = jnp.ones((2, 8), bool)
+    mode = jnp.full((2, 8), int(LayoutMode.DIST_HASH), jnp.int32)
+    ranks = jnp.arange(2, dtype=jnp.int32)[:, None]
+    dest = route_data(mode, 2, ph, cid, ranks, xp=jnp)
+    owner = route_meta(mode, 2, pol2.n_md_servers, ph, ranks, xp=jnp)
+    pcfg = bb.ExchangeConfig(
+        "compacted",
+        data_spec=bb.plan_mesh_ragged_spec(dest, valid, 2, n_chips=1,
+                                           executor="ppermute"),
+        meta_spec=bb.plan_mesh_ragged_spec(owner, valid, 2, n_chips=1,
+                                           executor="ppermute"))
+    write = build_mesh_ops(make_node_mesh(1), pol2, pcfg)[0]
+    dense = build_mesh_ops(make_node_mesh(1), pol2, bb.DENSE)[0]
+    got = write(bb.init_state(2, 16, 4, 16), mode, ph, cid, pay, valid)
+    want = dense(bb.init_state(2, 16, 4, 16), mode, ph, cid, pay, valid)
+    for a, b in zip(got.tree_flatten()[0], want.tree_flatten()[0]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     client = BBClient(policy, make_node_mesh(1), cap=16, words=4, mcap=16,
                       exchange="compacted", ragged=True)
     assert client.ragged is True                 # mesh plans ragged now
-    assert client._ppermute_ok is True           # 1 node on 1 device
+    assert client._n_chips == 1                  # 1 node on 1 device
 
 
 def test_exchange_footprint_scaling():

@@ -76,11 +76,12 @@ def test_build_executor_is_the_single_routing_decision():
     # meta role reads the meta spec slot, not the data one
     assert type(build_executor("meta", pol, q, cfg)).__name__ == \
         "UniformExecutor"
-    mspec = MeshRaggedSpec((8,) * N, (8,) * N, "padded")
+    mspec = MeshRaggedSpec("padded", 4, 16, (8,))
     ex = build_executor("data", pol, q,
                         bb.ExchangeConfig("compacted", data_spec=mspec))
-    assert type(ex).__name__ == "UniformExecutor" and ex.budget == 8
-    pspec = MeshRaggedSpec((8,) * N, (8,) * N, "ppermute")
+    assert type(ex).__name__ == "MeshSplitExecutor" and ex.spec == mspec
+    assert ex.carry_budget == 0
+    pspec = MeshRaggedSpec("ppermute", 4, 16, (8, 0, 0))
     ex = build_executor("data", pol, q,
                         bb.ExchangeConfig("compacted", data_spec=pspec))
     assert type(ex).__name__ == "PermuteExecutor"
@@ -88,15 +89,27 @@ def test_build_executor_is_the_single_routing_decision():
 
 def test_mesh_ragged_spec_validation():
     with pytest.raises(ValueError, match="executor"):
-        MeshRaggedSpec((1,), (1,), "bogus")
+        MeshRaggedSpec("bogus", 4, 8, (8,))
+    with pytest.raises(ValueError, match="fabric widths"):
+        MeshRaggedSpec("ppermute", 4, 8, (8,))
+    with pytest.raises(ValueError, match="fabric widths"):
+        MeshRaggedSpec("padded", 4, 8, (8, 8, 8))
     with pytest.raises(ValueError, match="per node"):
-        MeshRaggedSpec((1, 1), (1,), "padded")
-    spec = MeshRaggedSpec((8, 2, 0, 4), (8, 4, 0, 2), "ppermute")
-    assert spec.bmax == 8 and spec.total == 14 and spec.exchanged_cols == 6
-    assert list(spec.offsets) == [0, 8, 12, 12, 14]
-    # hashable → usable as a jit cache key inside ExchangeConfig
-    assert hash(spec) == hash(MeshRaggedSpec((8, 2, 0, 4), (8, 4, 0, 2),
-                                             "ppermute"))
+        MeshRaggedSpec("padded", 4, 8, (8,), budgets=(1, 1),
+                       round_widths=(1,))
+    spec = MeshRaggedSpec("ppermute", 4, 16, (8, 0, 4))
+    assert spec.segments == (8, 4) and spec.offsets == (1, 3)
+    assert spec.operand_cols == spec.exchanged_cols == 12
+    padded = MeshRaggedSpec("padded", 4, 16, (8,))
+    assert padded.segments == (8,) * 4 and padded.operand_cols == 32
+    assert padded.exchanged_cols == 24           # the self segment stays
+    # hashable → usable as a jit cache key inside ExchangeConfig; only
+    # what the executor runs is in the key, the per-node summaries not
+    twin = MeshRaggedSpec("ppermute", 4, 16, (8, 0, 4), budgets=(3,) * 4,
+                          round_widths=(1,) * 4, local_rows=5,
+                          fabric_rows=7, sizes=(16, 8, (8, 0, 4)))
+    assert twin == spec and hash(twin) == hash(spec)
+    assert MeshRaggedSpec("ppermute", 4, 32, (8, 0, 4)) != spec
 
 
 def test_plan_mesh_ragged_spec_measures_diagonals():
@@ -108,6 +121,8 @@ def test_plan_mesh_ragged_spec_measures_diagonals():
     spec = plan_mesh_ragged_spec(dest, valid, n, align=1)
     assert spec.round_widths == (0, q, 0, 0)
     assert spec.budgets == (q, q, q, q)      # every dest is SOME row's max
+    # one chip offset carries everything: the ring ships one segment
+    assert spec.executor == "ppermute" and spec.widths == (q, 0, 0)
     assert spec.exchanged_cols == q          # vs N·bmax = 4q padded
     # self traffic lands in round 0 — free
     dest0 = jnp.asarray([[i] * q for i in range(n)], jnp.int32)
@@ -118,36 +133,42 @@ def test_plan_mesh_ragged_spec_measures_diagonals():
 
 def test_permute_plan_covers_measured_traffic():
     """PermuteExecutor plans over a measured spec must have zero overflow
-    and serve every valid request (the ppermute losslessness invariant)."""
+    and serve every valid request (the ppermute losslessness invariant):
+    every row bound for another chip has its own send column, and rows
+    for the same chip ship nothing."""
     for seed in range(5):
         ph, cid, pay, valid, mode = _batch(seed)
-        pol = _mixed_policy()
         client = jnp.arange(N, dtype=jnp.int32)[:, None]
         dest = route_data(mode, N, ph, cid, client, xp=jnp)
-        spec = plan_mesh_ragged_spec(dest, valid, N, align=1)
-        pspec = MeshRaggedSpec(spec.budgets, spec.round_widths, "ppermute")
-        ex = PermuteExecutor(N, pspec)
-        plan = ex.plan(dest, valid, client=client)
-        assert int(np.asarray(plan.overflow).sum()) == 0
-        assert bool(np.asarray(ex.served(plan))[np.asarray(valid)].all())
-        # every valid request has a reply slot; no two requests share one
-        ri = np.asarray(plan.reply_idx)
-        v = np.asarray(valid)
-        assert (ri[v] >= 0).all()
-        for r in range(N):
-            slots = ri[r][v[r]]
-            assert len(set(slots.tolist())) == len(slots)
+        for chips in (2, 4, 8):
+            spec = plan_mesh_ragged_spec(dest, valid, N, n_chips=chips,
+                                         align=1, executor="ppermute")
+            ex = PermuteExecutor(N, spec)
+            plan = ex.plan(dest, valid, client=client)
+            assert int(np.asarray(plan.overflow).sum()) == 0
+            assert bool(np.asarray(ex.served(plan))[np.asarray(valid)].all())
+            L = N // chips
+            ri = np.asarray(plan.reply_idx).reshape(chips, L, Q)
+            v = np.asarray(valid).reshape(chips, L, Q)
+            d = np.asarray(dest).reshape(chips, L, Q)
+            for c in range(chips):
+                fab = v[c] & (d[c] // L != c)
+                assert (ri[c][fab] >= 0).all() and (ri[c][~fab] < 0).all()
+                slots = ri[c][fab]
+                assert len(set(slots.tolist())) == len(slots)
+                assert (slots < spec.operand_cols).all()
+            assert spec.fabric_rows == int((v & (d // L != np.arange(
+                chips)[:, None, None])).sum())
 
 
 # ---------------------------------------------------------------------------
 # executor parity: padded + ppermute vs ragged-stacked vs dense
 # ---------------------------------------------------------------------------
-def _spec_pair(dest, owner, valid, executor):
-    d = plan_mesh_ragged_spec(dest, valid, N, allow_ppermute=False)
-    m = plan_mesh_ragged_spec(owner, valid, N, allow_ppermute=False)
-    if executor == "ppermute":
-        d = MeshRaggedSpec(d.budgets, d.round_widths, "ppermute")
-        m = MeshRaggedSpec(m.budgets, m.round_widths, "ppermute")
+def _spec_pair(dest, owner, valid, executor, chips=N):
+    d = plan_mesh_ragged_spec(dest, valid, N, n_chips=chips,
+                              executor=executor)
+    m = plan_mesh_ragged_spec(owner, valid, N, n_chips=chips,
+                              executor=executor)
     return d, m
 
 
@@ -323,7 +344,7 @@ def test_two_phase_hybrid_read_parity():
                                       np.asarray(outs[k][1]))
     # the two-phase client actually planned a measured data spec for the
     # read (the one-phase client cannot — destinations are table state)
-    assert ("data", Q) in clients["two_phase"]._spec_floor
+    assert ("read", "data", Q) in clients["two_phase"]._spec_floor
     for a, b in zip(clients["dense"].stat(reqs["dense"]),
                     clients["two_phase"].stat(reqs["two_phase"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -349,7 +370,7 @@ def test_presizing_converges_to_one_spec():
     assert len({d for d, _ in warm}) == 1, "data specs did not converge"
     assert len({m for _, m in warm}) == 1, "meta specs did not converge"
     # floors only ever widen → later plans always cover earlier maxima
-    floors = client._spec_floor[("data", Q)]
+    floors = client._spec_floor[("write", "data", Q)]
     assert (np.asarray(specs[-1][0].budgets) >= 0).all()
     assert (floors >= np.asarray(specs[0][0].budgets)).all()
 
@@ -497,14 +518,12 @@ MESH_PLAN_SCRIPT = textwrap.dedent("""
     ranks = jnp.arange(8, dtype=jnp.int32)[:, None]
     dest = route_data(mode, 8, ph, cid, ranks, xp=jnp)
     owner = route_meta(mode, 8, pol.n_md_servers, ph, ranks, xp=jnp)
-    ds = bb.plan_mesh_ragged_spec(dest, valid, 8, allow_ppermute=False)
-    ms = bb.plan_mesh_ragged_spec(owner, valid, 8, allow_ppermute=False)
     cfg = bb.ExchangeConfig(
         "compacted",
-        data_spec=bb.MeshRaggedSpec(ds.budgets, ds.round_widths,
-                                    "ppermute"),
-        meta_spec=bb.MeshRaggedSpec(ms.budgets, ms.round_widths,
-                                    "ppermute"))
+        data_spec=bb.plan_mesh_ragged_spec(dest, valid, 8,
+                                           executor="ppermute"),
+        meta_spec=bb.plan_mesh_ragged_spec(owner, valid, 8,
+                                           executor="ppermute"))
     from repro.core.mesh_engine import build_mesh_ops
     write, read, meta, read_loc = build_mesh_ops(mesh, pol, cfg)
     dense_write = build_mesh_ops(mesh, pol, bb.DENSE)[0]
@@ -565,8 +584,8 @@ SHARDED_PLAN_SCRIPT = textwrap.dedent("""
     dest = rng.randint(0, 4, (4, 64)).astype(np.int32)
     valid = rng.rand(4, 64) < 0.8
     want = exchange_plan.plan_mesh_ragged_spec(dest, valid, 4)
-    # the chip's path: the Pallas histogram (interpreted here) planning
-    # from node-sharded outputs of a mesh op (a two-phase read's data_loc)
+    # the chip's path: planning from node-sharded outputs of a mesh op (a
+    # two-phase read's data_loc), with any device histogram interpreted
     exchange_plan.histogram_rows2d = functools.partial(
         dest_histogram2d_kernel, interpret=True)
     shard = NamedSharding(make_node_mesh(4), PS(NODE_AXIS))
@@ -579,8 +598,9 @@ SHARDED_PLAN_SCRIPT = textwrap.dedent("""
 
 @pytest.mark.mesh
 def test_mesh_ragged_spec_from_node_sharded_inputs():
-    """The mesh planner runs the Pallas histogram on node-sharded inputs
-    (regression: the kernel was handed the sharded array and refused it)."""
+    """The mesh planner plans from node-sharded inputs (regression: a
+    device histogram kernel was handed the sharded array and refused
+    it; the planner now copies both arrays to the host in one sync)."""
     r = subprocess.run([sys.executable, "-c", SHARDED_PLAN_SCRIPT],
                        capture_output=True, text=True, timeout=600,
                        cwd=str(ROOT))

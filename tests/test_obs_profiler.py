@@ -5,8 +5,9 @@ one write, one two-phase read and one create under ``jax.profiler``; the
 capture is read back with ``ProfileData``.  Every ``obs`` span is then a
 ``/host:CPU`` event of the same name: the op spans hold the resolve, plan,
 route and dispatch spans, and each blocking device-to-host read is one
-``client.sync.<site>`` span — two per write (data and metadata spec
-budgets), three per two-phase read (probe mask, the probe's metadata spec,
+``client.sync.<site>`` span — one per write (the metadata spec: a
+HYBRID write stores its data locally and plans no data exchange), three
+per two-phase read (probe mask, the probe's metadata spec,
 the data spec) and one per metadata call.
 """
 import glob
@@ -17,7 +18,7 @@ import pytest
 
 N, Q, W = 4, 8, 8
 OPS = ("client.write", "client.read", "client.meta")
-SYNCS = {"client.write": 2, "client.read": 3, "client.meta": 1}
+SYNCS = {"client.write": 1, "client.read": 3, "client.meta": 1}
 
 
 def _client(trace=None):
@@ -104,8 +105,8 @@ def test_sync_spans_per_call(captured):
         syncs = [s for s in warm if s[2].startswith("client.sync.")
                  and _inside(s, calls[0])]
         assert len(syncs) == want, (op, [s[2] for s in syncs])
-    # every sync of the capture lies in an op span: 2 + 3 + 1
-    assert sum(s[2].startswith("client.sync.") for s in warm) == 6
+    # every sync of the capture lies in an op span: 1 + 3 + 1
+    assert sum(s[2].startswith("client.sync.") for s in warm) == 5
     read = next(s for s in warm if s[2] == "client.read")
     sites = sorted(s[2] for s in warm if s[2].startswith("client.sync.")
                    and _inside(s, read))
